@@ -1335,13 +1335,8 @@ def bench_batch_routing(force=False):
     return rows, (f"fused wave routing: {sp:.1f}x decisions/sec at batch "
                   f"64, {top_n} instances vs the single-decision path "
                   f"({r[top_n][top_k]:.1f}us/decision; issue target >=5x)."
-                  f" On CPU the Pallas kernel runs under interpret mode,"
-                  f" where XLA per-op dispatch (~3us x ~20 ops/step)"
-                  f" floors the sequential feedback loop at ~60us/step —"
-                  f" the same per-op tax the numpy single path pays, so"
-                  f" wave amortization only materializes on real"
-                  f" accelerator execution (see ROADMAP 'Router"
-                  f" scaling')")
+                  f" A CPU run says nothing about the device path on"
+                  f" the TPU")
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,8 @@ def main() -> int:
                     help="ignore cached results")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.figures import ALL_BENCHES
 
     print("name,us_per_call,derived")

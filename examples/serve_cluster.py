@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.cluster.metrics import fmt_row, summarize
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.core import JSQPolicy, LMetricPolicy, SessionAffinityPolicy
 from repro.models import Model
@@ -32,15 +33,18 @@ from repro.workloads.sessions import (SESSIONS, SLO, Session,
                                       blocks_to_tokens, make_sessions)
 
 
-def build_workload(n, seed=0):
-    """Multi-app workload: 3 'applications' with shared system prompts."""
+def build_workload(n, seed=0, suffix_len=None):
+    """Multi-app workload: 3 'applications' with shared 96-token system
+    prompts.  Suffixes are 8-31 tokens, or all ``suffix_len`` long (a
+    fixed prompt length keeps the engines' prefill shapes few)."""
     rng = np.random.RandomState(seed)
     apps = [rng.randint(4, 500, size=96) for _ in range(3)]
     arrivals, t = [], 0.0
     for i in range(n):
         t += float(rng.exponential(0.05))
         app = rng.randint(3)
-        suffix = rng.randint(4, 500, size=rng.randint(8, 32))
+        n_sfx = rng.randint(8, 32)      # drawn either way: one stream per seed
+        suffix = rng.randint(4, 500, size=suffix_len or n_sfx)
         toks = np.concatenate([apps[app], suffix]).astype(np.int32)
         arrivals.append((t, toks, int(rng.randint(4, 12))))
     return arrivals
@@ -105,6 +109,7 @@ def main():
                          "next-turn feedback through the real engines")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     model = Model(cfg)
     params = model.init(jax.random.key(0))

@@ -8,7 +8,7 @@ import repro.launch.mesh as meshmod
 shape = sys.argv[2] if len(sys.argv)>2 else "train_4k"
 # monkeypatch mesh for probe
 if len(sys.argv)>3 and sys.argv[3] == "small":
-    meshmod.make_production_mesh = lambda multi_pod=False: jax.make_mesh((4,4), ("data","model"))
+    meshmod.make_production_mesh = lambda multi_pod=False: jax.make_mesh((4,4), ("data","model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 lowered, skip, cfg = build_lowered(sys.argv[1], shape, False)
 compiled = lowered.compile()
 mem = compiled.memory_analysis()

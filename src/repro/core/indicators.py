@@ -25,7 +25,8 @@ instance hooks:
 Policies score by vectorized expressions over these arrays (LMetric's
 ``(p_token + 1) * (bs + 1)`` is two fused array ops); nothing in the
 scoring path walks per-instance Python objects.  The arrays are the
-substrate later PRs jit through jax/pallas for batch routing.
+substrate the jitted wave loop (``repro.kernels.route_score``) scores
+for batch routing.
 
 ``InstanceState`` remains the mutation interface — it is a *view* over
 one column of the factory's arrays (attribute reads/writes hit the
@@ -67,7 +68,7 @@ the prefix index, one dirty flag per shard):
 
 * ``device_view()`` returns ``(r_bs, q_bs, queued_prefill_tokens,
   total_tokens)`` as jax arrays (int64 — created under
-  ``jax.experimental.enable_x64()``), re-uploading **only the shards
+  ``repro.kernels.route_score.x64()``), re-uploading **only the shards
   whose dirty flag is set** and caching the rest (with one shard —
   the default — that degenerates to the original whole-array
   behaviour).
@@ -1473,7 +1474,7 @@ class IndicatorFactory:
 
     def device_view(self):
         """(r_bs, q_bs, queued_prefill_tokens, total_tokens) as int64
-        jax arrays (created under ``jax.experimental.enable_x64``),
+        jax arrays (created under ``route_score.x64``),
         re-uploading only the mirror shards whose dirty flag is set
         since the last call.  With one shard (the default) this is one
         cached whole-array upload per mutation epoch, exactly the
@@ -1482,11 +1483,11 @@ class IndicatorFactory:
         redone.  The returned arrays are read-only by contract."""
         if self._dev is not None:
             return self._dev
-        import jax
         import jax.numpy as jnp
+        from repro.kernels.route_score import x64
         cols = (self.r_bs, self.q_bs, self.queued_prefill_tokens,
                 self.total_tokens)
-        with jax.experimental.enable_x64():  # keep the mirror int64
+        with x64():  # keep the mirror int64
             for s, (lo, hi) in enumerate(self._mirror_bounds):
                 if self._dirty[s] or self._dev_shards[s] is None:
                     self._dev_shards[s] = tuple(jnp.asarray(c[lo:hi])
@@ -1528,9 +1529,9 @@ class IndicatorFactory:
         fleet), mirroring the host-side contract."""
         if self._dev_hetero is not None:
             return self._dev_hetero
-        import jax
         import jax.numpy as jnp
-        with jax.experimental.enable_x64():
+        from repro.kernels.route_score import x64
+        with x64():
             shards = [(jnp.asarray(self.model_id[lo:hi]),
                        jnp.asarray(self.hardware_class[lo:hi]),
                        None if self.prefill_norm is None

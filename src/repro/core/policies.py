@@ -44,7 +44,7 @@ Two batch APIs sit next to ``route``:
 * ``plan_batch(reqs, factory, now)`` — the device half of
   ``Router.route_batch``: plans the wave's assignments with the fused
   sequential-argmin-with-feedback loop in ``repro.kernels.route_score``
-  (Pallas kernel for LMETRIC, jitted jax for JSQ/linear/filter).
+  (one jitted jax loop for every kind).
   Returns None when the policy (or factory) needs the host path:
   simulator-based policies (llm-d, PolyServe — predictor noise is a
   host-side stream), Dynamo (per-request max-normalisation), Preble
@@ -564,7 +564,7 @@ class LMetricPolicy(Policy):
     fires, suspected instances are filtered and the policy degrades to
     load-balance-only over the remainder, per the paper's retrofit.
 
-    Batch planning runs the route_score Pallas kernel for the
+    Batch planning runs the route_score wave loop for the
     "ptoken"/"one_minus_hit" × "bs"/"tokens" grid; the "cost" load
     indicator (latency-model arithmetic) and an attached detector
     (stateful per-decision Python phase machine) take the host fallback.

@@ -1,4 +1,4 @@
-"""Fused batch-routing score kernel (jax / Pallas).
+"""Fused batch-routing score loop (jax).
 
 Scores a whole arrival wave of ``k`` requests against ``n`` instances in
 one device computation.  The core is a *sequential argmin with feedback*:
@@ -16,11 +16,12 @@ floating-point operation order of the numpy scoring path in
 ``repro.core.policies`` (itself bit-compatible with the frozen scalar
 reference).  Two ingredients make that possible:
 
-* **x64**: callers run every entry point under
-  ``jax.experimental.enable_x64()`` (the public wrappers here do it for
-  you) so scores are float64 exactly like numpy.  On a real TPU f64 is
-  unavailable — there the kernel runs f32 and the bit-identity guarantee
-  is CPU/interpret-mode only (differential tests pin it there).
+* **x64**: every entry point runs under :func:`x64` (the public wrappers
+  here do it for you) so indicators are int64 and scores float64
+  exactly like numpy.  On a TPU, XLA emulates both types;
+  ``chip_smoke.py`` checks there that wave decisions still equal the
+  host's sequential ``route`` decisions, and the differential tests pin
+  the same contract on the CPU.
 * **intra-wave KV$ credit**: the host passes the pre-wave aggregated-
   index hit depths ``depth[k, n]`` plus the pairwise longest-common-
   prefix matrix ``lcp[k, k]`` of the wave's block chains.  After request
@@ -42,7 +43,7 @@ walks would produce; the device-mirror / dirty-flag contract in
 That independence extends to sharding: a sharded factory
 (``n_shards > 1``) concatenates per-shard hit vectors into the same
 full-width ``depth[k, n]`` matrix and slices the mirror per shard, so
-the kernel is oblivious to the host index's partitioning — the
+the loop is oblivious to the host index's partitioning — the
 ``depth``/``lcp``/``plen`` input schema here is the only coupling.
 
 Policy kinds
@@ -53,35 +54,28 @@ Policy kinds
 ``lmetric``  (P-token + 1) × (BS + 1) and §5.1 ablations   (Fig. 17b)
 ``ptoken``   raw P-token, first-min selection (PD-disagg prefill pool)
 
-``lmetric`` and ``ptoken`` run as a Pallas kernel (the paper policy is
-the production path); ``jsq``/``linear``/``filter`` run the same step
-body as a jitted ``lax.fori_loop``.  ``route_wave_ref`` exposes the pure
-jnp loop for every kind — the kernel's differential reference.
-
-``INTERPRET`` defaults to True (CPU container); on TPU flip it with
-``set_interpret(False)`` or REPRO_KERNELS_INTERPRET=0, matching
-``kernels.ops``.
+Every kind runs the same step body as one jitted ``lax.fori_loop``, on
+every platform.  ``route_wave`` pads the wave to a power-of-two length;
+``route_wave_ref`` runs the unpadded wave — the padding's differential
+reference.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import pallas as pl
 
 _EPS = 1e-9  # keep in sync with repro.core.policies._EPS
 
-INTERPRET = os.environ.get("REPRO_KERNELS_INTERPRET", "1") != "0"
 
-
-def set_interpret(v: bool):
-    global INTERPRET
-    INTERPRET = bool(v)
+def x64():
+    """The device dtype scope: int64 indicators, float64 scores.  Every
+    upload of routing state and every wave dispatch runs inside it."""
+    return jax.enable_x64(True)
 
 
 # ---------------------------------------------------------------------------
@@ -213,57 +207,13 @@ def _run_wave(kind, params, block_size, rbs, qbs, qpt, tt, depth, aux):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel (lmetric / ptoken kinds)
-# ---------------------------------------------------------------------------
-def _route_kernel(rbs_ref, qbs_ref, qpt_ref, tt_ref, depth_ref, aux_ref,
-                  sel_ref, hit_ref, *, kind, params, block_size):
-    """Whole-wave kernel: indicator rows + hit/LCP matrices live in VMEM;
-    the sequential feedback loop runs on-core with no host round-trips.
-    Grid is 1 — a wave is one kernel launch."""
-    sel, hit = _run_wave(
-        kind, params, block_size,
-        rbs_ref[0], qbs_ref[0], qpt_ref[0], tt_ref[0],
-        depth_ref[...], aux_ref[...])
-    sel_ref[0] = sel
-    hit_ref[0] = hit
-
-
-def _route_wave_pallas(kind, params, block_size, rbs, qbs, qpt, tt,
-                       depth, aux, interpret):
-    k, _ = depth.shape
-    sel, hit = pl.pallas_call(
-        functools.partial(_route_kernel, kind=kind, params=params,
-                          block_size=block_size),
-        out_shape=[
-            jax.ShapeDtypeStruct((1, k), jnp.int64),
-            jax.ShapeDtypeStruct((1, k), jnp.int64),
-        ],
-        interpret=interpret,
-    )(rbs[None], qbs[None], qpt[None], tt[None], depth, aux)
-    return sel[0], hit[0]
-
-
-# ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit,
                    static_argnames=("kind", "params", "block_size"))
-def _route_wave_jnp(kind, params, block_size, rbs, qbs, qpt, tt, depth,
-                    aux):
+def _route_wave(kind, params, block_size, rbs, qbs, qpt, tt, depth, aux):
     return _run_wave(kind, params, block_size, rbs, qbs, qpt, tt, depth,
                      aux)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("kind", "params", "block_size",
-                                    "interpret"))
-def _route_wave_kernel(kind, params, block_size, rbs, qbs, qpt, tt,
-                       depth, aux, interpret):
-    return _route_wave_pallas(kind, params, block_size, rbs, qbs, qpt,
-                              tt, depth, aux, interpret)
-
-
-_PALLAS_KINDS = ("lmetric", "ptoken")
 
 
 def _pack_aux(lcp, plen, tie0, kp):
@@ -279,8 +229,7 @@ def _pack_aux(lcp, plen, tie0, kp):
 
 
 def route_wave_submit(kind: str, params: tuple, block_size: int,
-                      rbs, qbs, qpt, tt, depth, lcp, plen, tie0: int,
-                      use_pallas: bool = True):
+                      rbs, qbs, qpt, tt, depth, lcp, plen, tie0: int):
     """Dispatch a wave to the device and return a handle — the **score
     stage boundary**.  jax dispatch is asynchronous: the jitted wave
     loop is enqueued and the call returns immediately with device
@@ -305,14 +254,11 @@ def route_wave_submit(kind: str, params: tuple, block_size: int,
     if kp != k:
         depth = np.pad(np.asarray(depth), ((0, kp - k), (0, 0)))
     aux = _pack_aux(lcp, plen, tie0, kp)
-    with jax.experimental.enable_x64():
-        args = (jnp.asarray(rbs), jnp.asarray(qbs), jnp.asarray(qpt),
-                jnp.asarray(tt), jnp.asarray(depth), jnp.asarray(aux))
-        if use_pallas and kind in _PALLAS_KINDS:
-            sel, hit = _route_wave_kernel(kind, params, block_size,
-                                          *args, interpret=INTERPRET)
-        else:
-            sel, hit = _route_wave_jnp(kind, params, block_size, *args)
+    with x64():
+        sel, hit = _route_wave(kind, params, block_size, jnp.asarray(rbs),
+                               jnp.asarray(qbs), jnp.asarray(qpt),
+                               jnp.asarray(tt), jnp.asarray(depth),
+                               jnp.asarray(aux))
     return sel, hit, k
 
 
@@ -320,26 +266,26 @@ def route_wave_collect(handle) -> Tuple[np.ndarray, np.ndarray]:
     """Block on a :func:`route_wave_submit` handle; returns the wave's
     (assignments, hit tokens) as host numpy arrays (padding stripped)."""
     sel, hit, k = handle
-    return np.asarray(sel[:k]), np.asarray(hit[:k])
+    return np.asarray(sel)[:k], np.asarray(hit)[:k]
 
 
 def route_wave(kind: str, params: tuple, block_size: int,
-               rbs, qbs, qpt, tt, depth, lcp, plen, tie0: int,
-               use_pallas: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+               rbs, qbs, qpt, tt, depth, lcp, plen,
+               tie0: int) -> Tuple[np.ndarray, np.ndarray]:
     """Route a whole wave on device; returns (assignments, hit tokens).
     Submit + collect in one breath — see :func:`route_wave_submit`."""
     return route_wave_collect(route_wave_submit(
         kind, params, block_size, rbs, qbs, qpt, tt, depth, lcp, plen,
-        tie0, use_pallas=use_pallas))
+        tie0))
 
 
 def route_wave_ref(kind, params, block_size, rbs, qbs, qpt, tt, depth,
                    lcp, plen, tie0):
-    """Pure-jnp wave loop for every kind — the kernel's differential
-    reference (no padding, no Pallas)."""
+    """The wave loop on the unpadded wave — the differential reference
+    for :func:`route_wave`'s power-of-two padding."""
     aux = _pack_aux(lcp, plen, tie0, len(plen))
-    with jax.experimental.enable_x64():
-        sel, hit = _route_wave_jnp(
+    with x64():
+        sel, hit = _route_wave(
             kind, params, block_size, jnp.asarray(rbs), jnp.asarray(qbs),
             jnp.asarray(qpt), jnp.asarray(tt), jnp.asarray(depth),
             jnp.asarray(aux))

@@ -15,7 +15,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     model=16) for the 2-pod 512-chip deployment."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def fsdp_axes(multi_pod: bool = False):
@@ -25,4 +26,5 @@ def fsdp_axes(multi_pod: bool = False):
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

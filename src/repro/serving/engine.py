@@ -12,7 +12,8 @@ are prefilled — the paper's compute skip, for real.
 ``EngineCluster`` wires N engines to a ``core.Router`` under a
 virtual-time event loop whose step durations are the *measured* wall
 times of the JAX computations, giving honest relative TTFT/TPOT between
-policies on CPU.
+policies.  Each engine may be pinned to a device (its params and cache
+live there); engines on one device share one copy of the params.
 
 Encoder-decoder archs (whisper) are not served by this engine (the
 cluster simulator covers their scheduling); everything decoder-only —
@@ -21,10 +22,11 @@ dense, MoE, SSM, hybrid — works.
 from __future__ import annotations
 
 import collections
+import functools
 import heapq
 import itertools
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,23 +72,38 @@ def _zero_slot(cache, b: int):
 class PrefixStore:
     """Host-side LRU store of per-slot cache fragments keyed by block-id
     chains.  ``exact_only`` archs (recurrent) store state snapshots; the
-    mechanism is identical — inject fragment, resume at its length."""
+    mechanism is identical — inject fragment, resume at its length.
 
-    def __init__(self, capacity: int = 64):
+    ``partial``: a fragment also serves a prompt that shares only the
+    first blocks of its chain.  That is exact for full-attention KV
+    (position p's K/V depend on tokens <= p, and the engine masks the
+    cache past the reused length); a recurrent snapshot or a wrapped
+    sliding-window ring holds the whole chain and needs all of it."""
+
+    def __init__(self, capacity: int = 64, partial: bool = False):
         self.capacity = capacity
+        self.partial = partial
         self._store: "collections.OrderedDict[Tuple, Tuple]" = \
             collections.OrderedDict()
 
     def lookup(self, blocks: Tuple[int, ...]):
-        """Longest stored chain that is a prefix of ``blocks``."""
-        best = None
-        for L in range(len(blocks), 0, -1):
-            key = blocks[:L]
-            if key in self._store:
-                self._store.move_to_end(key)
-                frag, length = self._store[key]
-                return key, frag, length
-        return None, None, 0
+        """Longest reusable stored prefix of ``blocks``: (key, fragment,
+        reusable tokens), or (None, None, 0)."""
+        best, best_n = None, 0
+        for key in self._store:
+            m = min(len(key), len(blocks))
+            n = 0
+            while n < m and key[n] == blocks[n]:
+                n += 1
+            if n < len(key) and not self.partial:
+                continue
+            if n > 0 and n >= best_n:
+                best, best_n = key, n
+        if best is None:
+            return None, None, 0
+        self._store.move_to_end(best)
+        frag, length = self._store[best]
+        return best, frag, length * best_n // len(best)
 
     def insert(self, blocks: Tuple[int, ...], frag, length: int):
         if not blocks:
@@ -101,54 +118,68 @@ class PrefixStore:
 
 class _Seq:
     __slots__ = ("req", "tokens", "slot", "pos", "generated", "out_tokens",
-                 "prefill_done")
+                 "prefill_done", "reused")
 
     def __init__(self, req: Request, tokens: np.ndarray, slot: int):
         self.req = req
         self.tokens = tokens
         self.slot = slot
         self.pos = 0                 # tokens already in cache
+        self.reused = 0              # of them, injected from the store
         self.generated = 0
         self.out_tokens: List[int] = []
         self.prefill_done = False
+
+
+# The engine's two device programs.  ``model`` is static, so every
+# engine serving one model shares one compile per shape and device.
+@functools.partial(jax.jit, static_argnums=0)
+def prefill_slot(model: Model, params, cache, tokens, positions, cache_len,
+                 b):
+    """Prefill one chunk into slot ``b``; returns (last-token logits,
+    cache)."""
+    sub = _slice_slot(cache, b)
+    logits, new_sub = model.prefill_cached(
+        params, tokens, positions, sub, cache_len[None])
+    return logits[:, -1], _write_slot(cache, new_sub, b)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def decode_step(model: Model, params, cache, tokens, pos):
+    """One greedy decode step for every slot; returns (next tokens,
+    cache)."""
+    logits, cache = model.decode_step(params, tokens, pos, cache)
+    return jnp.argmax(logits[:, -1], axis=-1), cache
 
 
 class InstanceEngine:
     def __init__(self, model: Model, params, iid: int = 0,
                  max_batch: int = 8, max_len: int = 512,
                  chunk_tokens: int = 128, block_size: int = 16,
-                 prefix_capacity: int = 64):
+                 prefix_capacity: int = 64, device=None):
+        """``device``: where this engine's params and KV cache live
+        (``None``: JAX's default device, params left where they are)."""
         assert not model.cfg.is_encdec, "enc-dec not served by this engine"
         self.model = model
-        self.params = params
+        self.device = device
+        self.params = params if device is None else \
+            jax.device_put(params, device)
         self.iid = iid
         self.max_batch = max_batch
         self.max_len = max_len
         self.chunk = chunk_tokens
         self.block_size = block_size
         self.cache = model.init_cache(max_batch, max_len)
-        self.prefix_store = PrefixStore(prefix_capacity)
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
+        self.prefix_store = PrefixStore(
+            prefix_capacity,
+            partial=all(k == "attn" for k in model.cfg.block_pattern))
         self.waiting: collections.deque = collections.deque()
         self.running: Dict[int, _Seq] = {}      # slot -> seq
         self.free_slots = list(range(max_batch))
         self._last_tokens = np.zeros(max_batch, np.int64)
         self._pos = np.zeros(max_batch, np.int64)
-
-        cfg = model.cfg
-
-        def prefill_slot(params, cache, tokens, positions, cache_len, b):
-            sub = _slice_slot(cache, b)
-            logits, new_sub = model.prefill_cached(
-                params, tokens, positions, sub, cache_len[None])
-            return logits[:, -1], _write_slot(cache, new_sub, b)
-
-        def decode(params, cache, tokens, pos):
-            logits, cache = model.decode_step(params, tokens, pos, cache)
-            nxt = jnp.argmax(logits[:, -1], axis=-1)
-            return nxt, cache
-
-        self._prefill = jax.jit(prefill_slot)
-        self._decode = jax.jit(decode)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request, tokens: np.ndarray):
@@ -177,7 +208,7 @@ class InstanceEngine:
             usable = min(length, len(seq.tokens) - 1)
             if usable > 0:
                 self.cache = _write_slot(self.cache, frag, slot)
-                seq.pos = usable
+                seq.pos = seq.reused = usable
                 seq.req.hit_tokens = usable
         return seq
 
@@ -197,9 +228,9 @@ class InstanceEngine:
             positions = jnp.arange(seq.pos, seq.pos + n,
                                    dtype=jnp.int32)[None]
             cache_len = jnp.asarray(seq.pos, jnp.int32)
-            logits, self.cache = self._prefill(
-                self.params, self.cache, toks, positions, cache_len,
-                seq.slot)
+            logits, self.cache = prefill_slot(
+                self.model, self.params, self.cache, toks, positions,
+                cache_len, seq.slot)
             logits.block_until_ready()
             events["prefill_tokens"] = n
             seq.pos += n
@@ -229,8 +260,8 @@ class InstanceEngine:
             events["decode_bs"] = len(self.running)
             toks = jnp.asarray(self._last_tokens[:, None], jnp.int32)
             pos = jnp.asarray(self._pos, jnp.int32)
-            nxt, self.cache = self._decode(self.params, self.cache, toks,
-                                           pos)
+            nxt, self.cache = decode_step(self.model, self.params,
+                                          self.cache, toks, pos)
             nxt = np.asarray(nxt)
             for slot, seq in list(self.running.items()):
                 tok = int(nxt[slot])
@@ -254,11 +285,11 @@ class InstanceEngine:
         """Trigger jit compiles so measured step times are steady-state."""
         toks = jnp.zeros((1, min(self.chunk, 8)), jnp.int32)
         pos = jnp.arange(toks.shape[1], dtype=jnp.int32)[None]
-        _, c = self._prefill(self.params, self.cache, toks, pos,
-                             jnp.asarray(0, jnp.int32), 0)
+        _, c = prefill_slot(self.model, self.params, self.cache, toks, pos,
+                            jnp.asarray(0, jnp.int32), 0)
         t = jnp.zeros((self.max_batch, 1), jnp.int32)
         p = jnp.zeros((self.max_batch,), jnp.int32)
-        self._decode(self.params, c, t, p)[0].block_until_ready()
+        decode_step(self.model, self.params, c, t, p)[0].block_until_ready()
 
 
 # ---------------------------------------------------------------------------
@@ -268,15 +299,23 @@ class EngineCluster:
 
     def __init__(self, n_instances: int, model: Model, params, policy,
                  block_size: int = 16, kv_capacity_tokens: int = 1 << 62,
-                 **engine_kw):
-        self.engines = [InstanceEngine(model, params, iid=i,
+                 devices: Optional[Sequence] = None, **engine_kw):
+        """``devices``: engine ``i`` runs on ``devices[i % len(devices)]``
+        (``None``: every engine on JAX's default device).  The params are
+        copied once to each distinct device."""
+        devs = [devices[i % len(devices)] if devices else None
+                for i in range(n_instances)]
+        placed = {d: params if d is None else jax.device_put(params, d)
+                  for d in set(devs)}
+        self.engines = [InstanceEngine(model, placed[d], iid=i, device=d,
                                        block_size=block_size, **engine_kw)
-                        for i in range(n_instances)]
+                        for i, d in enumerate(devs)]
         exact_only = not model.cfg.has_kv_blocks
         self.router = Router(policy, n_instances,
                              kv_capacity_tokens=kv_capacity_tokens,
                              block_size=block_size, exact_only=exact_only)
         self.block_size = block_size
+        self.served: Dict[int, _Seq] = {}   # rid -> finished sequence
 
     def run(self, arrivals: List[Tuple[float, np.ndarray, int]],
             verbose: bool = False, feedback=None) -> List[Request]:
@@ -291,6 +330,7 @@ class EngineCluster:
         for e in self.engines:
             e.warmup()
         finished: List[Request] = []
+        self.served = {}
         heap: List = []
         seqno = itertools.count()
         rids = itertools.count()
@@ -342,6 +382,7 @@ class EngineCluster:
                     seq.req.t_finish = now
                     self.router.on_finish(iid, seq.req)
                     finished.append(seq.req)
+                    self.served[seq.req.rid] = seq
                     if feedback is not None:
                         for entry in feedback(seq.req, now):
                             push(*entry)

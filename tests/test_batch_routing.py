@@ -8,7 +8,7 @@ calls.  We prove it three ways over a ~2k-request hotspot trace:
 
 1. batch vs sequential ``route`` on the *vectorized numpy* policies,
 2. batch vs the frozen scalar reference (``repro.core.scalar_ref``),
-3. the Pallas kernel vs the pure-jnp wave loop on random state.
+3. the padded device wave vs the unpadded wave loop on random state.
 
 A deterministic partial-drain schedule keeps every indicator nonzero and
 varying; finite KV$ capacity makes mid-wave evictions happen, so the
@@ -225,9 +225,11 @@ def test_eviction_mid_batch_falls_back(trace):
 
 
 # ---------------------------------------------------------------------------
-# kernel vs pure-jnp reference on random state
+# padded wave vs unpadded reference on random state
 # ---------------------------------------------------------------------------
-def test_route_kernel_matches_jnp_ref():
+def test_route_wave_padded_matches_unpadded_ref():
+    """k=24 pads to 32: the padding rows route after every real request
+    and must not change a real decision, for every kernel score mode."""
     from repro.kernels import route_score as rs
     rng = np.random.RandomState(3)
     n, k, bs = 32, 24, 64
@@ -241,10 +243,11 @@ def test_route_kernel_matches_jnp_ref():
             (rng.randint(4, 10, k) * bs).astype(np.int64))
     for kind, params in (("lmetric", ("ptoken", "bs")),
                          ("lmetric", ("one_minus_hit", "tokens")),
-                         ("ptoken", ())):
-        sel_k, hit_k = rs.route_wave(kind, params, bs, *args, 5,
-                                     use_pallas=True)
+                         ("ptoken", ()), ("jsq", ()), ("linear", (0.5,)),
+                         ("filter", (4,))):
+        sel_k, hit_k = rs.route_wave(kind, params, bs, *args, 5)
         sel_r, hit_r = rs.route_wave_ref(kind, params, bs, *args, 5)
+        assert sel_k.shape == sel_r.shape == (k,), kind
         assert (sel_k == sel_r).all() and (hit_k == hit_r).all(), kind
 
 

@@ -1,0 +1,94 @@
+"""Compile rehearsals for one TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed beside JAX, and it compiles for a chip
+that is described rather than present.  These tests compile the served
+path's device programs at their real sizes: the router's wave loop and
+a full-width qwen3_4b engine's jitted ``decode`` and ``prefill_slot``.
+A compile that passes says nothing about results or times; it says the
+chip's compiler takes the program and that the program fits the chip's
+16 GiB of HBM.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+HBM_BYTES = 16 * 2**30
+ENGINE_BATCH, ENGINE_LEN, CHUNK = 4, 256, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _assert_fits(compiled):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 0 < used <= HBM_BYTES, used
+
+
+@pytest.mark.parametrize("n,k", [(16384, 64), (256, 1)])
+@pytest.mark.parametrize("kind,params", [("lmetric", ("ptoken", "bs")),
+                                         ("jsq", ())])
+def test_wave_loop_compiles_for_v5e(one_chip, kind, params, n, k):
+    from repro.kernels.route_score import _route_wave, x64
+    with x64():
+        def i64(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int64, sharding=one_chip)
+        compiled = _route_wave.lower(
+            kind, params, 64, i64(n), i64(n), i64(n), i64(n), i64(k, n),
+            i64(k, k + 2)).compile()
+    _assert_fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def qwen3_4b(one_chip):
+    """Full-width qwen3_4b: the model, and its params and engine cache
+    as shapes on the described chip."""
+    from repro.configs import get_config
+    from repro.models import Model
+    model = Model(get_config("qwen3_4b"))
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    cache = jax.eval_shape(
+        lambda: model.init_cache(ENGINE_BATCH, ENGINE_LEN))
+    return model, _on(one_chip, params), _on(one_chip, cache)
+
+
+def test_engine_decode_compiles_for_v5e(one_chip, qwen3_4b):
+    from repro.serving.engine import decode_step
+    model, params, cache = qwen3_4b
+    compiled = decode_step.lower(
+        model, params, cache,
+        jax.ShapeDtypeStruct((ENGINE_BATCH, 1), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((ENGINE_BATCH,), jnp.int32,
+                             sharding=one_chip)).compile()
+    _assert_fits(compiled)
+
+
+def test_engine_prefill_slot_compiles_for_v5e(one_chip, qwen3_4b):
+    from repro.serving.engine import prefill_slot
+    model, params, cache = qwen3_4b
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = prefill_slot.lower(model, params, cache, i32(1, CHUNK),
+                                  i32(1, CHUNK), i32(), i32()).compile()
+    _assert_fits(compiled)

@@ -112,3 +112,61 @@ def test_prefix_hit_preserves_output(setup):
     assert seq1.req.hit_tokens == 0
     assert seq2.req.hit_tokens >= 48, "second serve must hit the prefix"
     assert out1 == out2, "cache-hit serve must be exact"
+
+
+def test_partial_prefix_hit_matches_cold_serve(setup):
+    """A prompt that shares only the first blocks of a stored chain
+    reuses those blocks' KV, and emits what a cold serve emits."""
+    cfg, m, params = setup
+    rng = np.random.RandomState(7)
+    shared = rng.randint(4, 500, size=48)
+    a, b = (np.concatenate([shared, rng.randint(4, 500, size=16)])
+            .astype(np.int32) for _ in range(2))
+
+    def serve(eng, toks):
+        req = Request(rid=0, arrival=0.0, blocks=(), prompt_len=64,
+                      output_len=5)
+        eng.submit(req, toks)
+        for _ in range(100):
+            ev = eng.step()
+            if ev["finished"]:
+                return ev["finished"][0]
+        raise AssertionError("did not finish")
+
+    def engine():
+        return InstanceEngine(m, params, max_batch=2, max_len=128,
+                              chunk_tokens=32, block_size=16)
+
+    warm = engine()
+    serve(warm, a)
+    hit = serve(warm, b)
+    cold = serve(engine(), b)
+    assert hit.reused == 48 and cold.reused == 0
+    assert hit.out_tokens == cold.out_tokens
+
+
+@pytest.mark.parametrize("partial,want", [(False, 0), (True, 32)])
+def test_prefix_store_partial_lookup(partial, want):
+    """Only a partial store serves a prompt that diverges inside a
+    stored chain; both serve a stored chain that is a whole prefix."""
+    from repro.serving.engine import PrefixStore
+    store = PrefixStore(partial=partial)
+    store.insert((1, 2, 3, 4), "frag", 64)
+    assert store.lookup((1, 2, 9))[2] == want
+    assert store.lookup((1, 2, 3, 4, 5))[1:] == ("frag", 64)
+    assert store.lookup((7,)) == (None, None, 0)
+
+
+def test_cluster_places_engines_on_devices(setup):
+    """Each engine's params and cache live on its device; engines on
+    one device share one copy of the params."""
+    cfg, m, params = setup
+    dev = jax.devices()[0]
+    cluster = EngineCluster(2, m, params, LMetricPolicy(), devices=[dev],
+                            block_size=16, max_batch=2, max_len=64)
+    for e in cluster.engines:
+        assert e.device == dev
+        for leaf in jax.tree.leaves((e.params, e.cache)):
+            assert leaf.devices() == {dev}
+    a, b = (jax.tree.leaves(e.params)[0] for e in cluster.engines)
+    assert a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()

@@ -23,7 +23,7 @@ from repro.core.indicators import AggregatedPrefixIndex
 from repro.core.sharded_index import ShardedPrefixIndex
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import HealthCheck, given, settings
     import hypothesis.strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -202,18 +202,17 @@ def test_unknown_backend_rejected():
 # hypothesis-driven interleavings (optional dev dep)
 # ---------------------------------------------------------------------------
 if HAVE_HYPOTHESIS:
-    _LIVE = {}
-
-    @pytest.fixture(scope="module")
+    @pytest.fixture
     def live_backends():
-        """Long-lived sharded indexes reused across hypothesis examples
-        (process workers are too expensive to respawn per example);
-        reset between examples by removing every instance."""
-        yield _LIVE
-        for trio in _LIVE.values():
+        """Long-lived sharded indexes reused across one test's hypothesis
+        examples (process workers are too expensive to respawn per
+        example); reset between examples by removing every instance, and
+        closed when the test ends, so no worker outlives it."""
+        live = {}
+        yield live
+        for trio in live.values():
             for ix in trio.values():
                 ix.close()
-        _LIVE.clear()
 
     @st.composite
     def interleavings(draw):
@@ -243,7 +242,9 @@ if HAVE_HYPOTHESIS:
                 ops.append(("walk", [tuple(q) for q in qs]))
         return ops
 
-    @settings(max_examples=15, deadline=None)
+    # the fixture is set up once per test, not per example, on purpose
+    @settings(max_examples=15, deadline=None,
+              suppress_health_check=[HealthCheck.function_scoped_fixture])
     @given(ops=interleavings(),
            n_shards=st.sampled_from(SHARD_COUNTS))
     @pytest.mark.process
@@ -304,3 +305,17 @@ def test_backend_equivalence_16384_instances(n_shards):
         for ix in idxs.values():
             ix.close()
     assert not _live_workers()
+
+
+def test_core_import_leaves_jax_unloaded():
+    """Process-backend shard workers import ``repro.core`` and must never
+    touch JAX: a process that loads it could take the accelerator from
+    the parent that serves on it."""
+    import subprocess
+    import sys
+    code = ("import sys, repro.core, repro.core.shard_backends; "
+            "assert not any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules), 'repro.core imported jax'")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120,
+                   env={**os.environ, "PYTHONPATH": src})

@@ -30,7 +30,8 @@ from repro.training.optim import OptimizerConfig, adamw_init
 from repro.training.train_loop import make_train_step
 
 out = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch in sys.argv[1:]:
     cfg = get_config(arch + "-smoke")
     model = Model(cfg)
