@@ -88,7 +88,7 @@ class PDDisaggSim:
                 self.pf, reqs,
                 lambda j, req: self._admit_prefill(req, int(sel[j]),
                                                    int(hits[j])),
-                self._on_arrival)
+                lambda tail: [self._on_arrival(r) for r in tail])
         else:
             for req in reqs:
                 self._on_arrival(req)

@@ -204,11 +204,13 @@ class RoutingPipeline:
         contract the monolithic path had).
 
         With an obs bundle attached (``Router(..., obs=...)``) the wave
-        additionally emits a nested span tree (wave > walk/score/commit,
-        sampled every Nth wave), speculation consume/discard instants,
-        per-shard walk marks on the shard workers' pid tracks, and
-        per-stage duration histograms into the metrics registry.  With
-        the default ``obs=None`` none of this code runs — the stage
+        additionally emits a nested span tree (wave > walk, score >
+        score.submit/score.collect, commit > commit.fallback; sampled
+        every Nth wave on the virtual clock), speculation
+        consume/discard instants, per-stage duration histograms, and the
+        ``pipeline.fallback_waves``/``pipeline.fallback_requests``
+        counters of the sequential re-route after a mid-wave eviction.
+        With the default ``obs=None`` none of this code runs — the stage
         sequence below is byte-for-byte the pre-observability path
         (Contract 5)."""
         from .router import commit_wave_plan
@@ -221,7 +223,8 @@ class RoutingPipeline:
         wave_span = None
         if tr is not None:
             tr.wave_tick()
-            wave_span = tr.span("wave", args={"k": len(reqs)})
+            wave_span = tr.span("wave", args={"wave": self.waves,
+                                              "k": len(reqs)})
             wave_span.__enter__()
         t0 = time.perf_counter_ns()
         with (tr.span("walk") if tr is not None else _NULL_CTX):
@@ -230,15 +233,18 @@ class RoutingPipeline:
             else:
                 self.drop_prefetch()
                 wave = policy.wave_inputs(reqs, factory)
-        if tr is not None and tr._sampled:
-            self._shard_marks(tr)
         t1 = time.perf_counter_ns()
         with (tr.span("score") if tr is not None else _NULL_CTX):
-            handle = policy.plan_submit(wave, factory)
+            # device-view upload, wave inputs to the device, dispatch
+            with (tr.span("score.submit") if tr is not None
+                  else _NULL_CTX):
+                handle = policy.plan_submit(wave, factory)
             tp0 = time.perf_counter_ns()
             self._maybe_prefetch(tracer=tr)
             tp = time.perf_counter_ns() - tp0  # prefetch is walk work
-            sel, _ = policy.plan_collect(handle)
+            with (tr.span("score.collect") if tr is not None
+                  else _NULL_CTX):
+                sel, _ = policy.plan_collect(handle)
         t2 = time.perf_counter_ns()
         self.walk_ns += (t1 - t0) + tp
         self.score_ns += (t2 - t1) - tp
@@ -264,9 +270,18 @@ class RoutingPipeline:
             router.routed += 1
             return iid
 
+        def fallback(tail):
+            # a commit's insert evicted: the plan's hits no longer hold,
+            # so the rest of the wave routes one request at a time
+            if reg is not None:
+                reg.inc("pipeline.fallback_waves")
+                reg.inc("pipeline.fallback_requests", len(tail))
+            with (tr.span("commit.fallback", args={"k": len(tail)})
+                  if tr is not None else _NULL_CTX):
+                return [router.route(r, now) for r in tail]
+
         with (tr.span("commit") if tr is not None else _NULL_CTX):
-            out = commit_wave_plan(factory, reqs, commit,
-                                   lambda r: router.route(r, now))
+            out = commit_wave_plan(factory, reqs, commit, fallback)
         t3 = time.perf_counter_ns()
         self.commit_ns += t3 - t2
         self.waves += 1
@@ -286,18 +301,6 @@ class RoutingPipeline:
         if k:
             factory.anti_entropy_step(k)
         return out
-
-    def _shard_marks(self, tr):
-        """Per-shard walk marks on the shard workers' pid tracks: the
-        parent emits on each worker's behalf (workers cannot append to
-        the trace), with the cumulative walk count as the
-        deterministic payload."""
-        backend = getattr(self.router.factory._agg, "backend", None)
-        if backend is None:
-            return
-        walks = backend.shard_walks
-        for s in range(len(walks)):
-            tr.shard_mark(s, "walk", args={"walks": int(walks[s])})
 
     # ------------------------------------------------------------------
     def stage_stats(self) -> dict:

@@ -34,7 +34,8 @@ def commit_wave_plan(factory: IndicatorFactory, reqs: Sequence[Request],
     The plan's hit model is exact *unless* a commit's KV$ insert evicts
     (caches only grow otherwise): snapshot the factory's eviction
     counter first, re-check it before every commit, and hand the rest of
-    the wave to ``fallback`` (sequential routing) the moment it moves.
+    the wave to ``fallback`` (sequential routing of that tail, returning
+    its decisions) the moment it moves.
     This ordering is the bit-identity invariant shared by
     ``Router.route_batch`` and ``PDDisaggSim._on_arrivals`` — keep it in
     one place.
@@ -43,7 +44,7 @@ def commit_wave_plan(factory: IndicatorFactory, reqs: Sequence[Request],
     out: List = []
     for j, req in enumerate(reqs):
         if factory.evictions != ev0:
-            out.extend(fallback(r) for r in reqs[j:])
+            out.extend(fallback(reqs[j:]))
             return out
         out.append(commit(j, req))
     return out

@@ -18,32 +18,41 @@ here; they are histogram samples in the metrics registry
 (``pipeline.walk_us`` …), which ``scripts/trace_report.py`` joins with
 the trace timeline.
 
-**Pid/tid mapping.**  The router/simulator tier is ``pid 0``; shard
-worker ``s`` is ``pid 1 + s`` (one process per shard under the process
-backend — the mapping every backend shares so traces are comparable
-across backends).  :meth:`process_name` emits the ``process_name``
-metadata rows Perfetto uses for track labels.
+**Pid/tid mapping.**  The router/simulator tier is ``pid 0``;
+:meth:`process_name` emits the ``process_name`` metadata rows Perfetto
+uses for track labels.
 
 **Sampling.**  ``sample_every=N`` records the span tree for every Nth
 wave only (``wave_tick`` advances the counter); instant events — drops,
 retractions, churn — are rare and always recorded.  Sampling is the
 overhead knob the ≤5 % enabled-mode budget is enforced against.
+
+**Profiler clock.**  ``SpanTracer(clock="profiler")`` records nothing
+itself: each span is a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` with the span's args as its metadata, and an instant
+is a zero-length one, so the spans land on the device trace's clock
+inside a ``jax.profiler.start_trace`` window (and cost about a
+microsecond each outside one).  Every wave is recorded (the profiler
+window bounds the volume); the profiler writes the trace, so
+``to_json``/``export`` refuse in this mode.  The simulators keep the
+virtual clock.
 """
 from __future__ import annotations
 
 import json
 from typing import Dict, List, Optional
 
-#: pid of the router/simulator tier; shard worker ``s`` is ``1 + s``
+#: pid of the router/simulator tier
 ROUTER_PID = 0
 
 #: default wave-sampling stride (every Nth wave gets a span tree)
 DEFAULT_SAMPLE_EVERY = 8
 
+#: the clocks a tracer runs on (``SpanTracer(clock=...)``)
+TRACE_CLOCKS = ("virtual", "profiler")
 
-def shard_pid(s: int) -> int:
-    """The trace pid assigned to shard worker ``s``."""
-    return 1 + s
+#: name prefix of the profiler clock's annotations
+PROFILER_PREFIX = "repro."
 
 
 class _Span:
@@ -76,15 +85,22 @@ _NULL_SPAN = _Span(None, "", 0, 0, None)
 
 class SpanTracer:
     def __init__(self, sample_every: int = DEFAULT_SAMPLE_EVERY,
-                 max_events: int = 1 << 20):
+                 max_events: int = 1 << 20, clock: str = "virtual"):
+        if clock not in TRACE_CLOCKS:
+            raise ValueError(f"unknown trace clock {clock!r} "
+                             f"(one of {TRACE_CLOCKS})")
         self.sample_every = max(int(sample_every), 1)
         self.max_events = max_events
         self.events: List[dict] = []
         self._ts = 0            # microsecond cursor (virtual, monotonic)
         self._wave = 0
         self._sampled = True
-        self._named_pids: Dict[int, str] = {}
-        self.process_name(ROUTER_PID, "router")
+        self._annotation = None
+        if clock == "profiler":
+            import jax.profiler
+            self._annotation = jax.profiler.TraceAnnotation
+        else:
+            self.process_name(ROUTER_PID, "router")
 
     # ---- virtual clock ------------------------------------------------
     def set_time(self, t_seconds: float):
@@ -98,8 +114,10 @@ class SpanTracer:
     # ---- sampling -----------------------------------------------------
     def wave_tick(self) -> bool:
         """Advance the wave counter; returns whether this wave's span
-        tree is recorded (every ``sample_every``-th wave)."""
-        self._sampled = (self._wave % self.sample_every) == 0
+        tree is recorded (every ``sample_every``-th wave; every wave on
+        the profiler clock)."""
+        self._sampled = (self._annotation is not None
+                         or self._wave % self.sample_every == 0)
         self._wave += 1
         return self._sampled
 
@@ -115,8 +133,10 @@ class SpanTracer:
         self.events.append(ev)
 
     def span(self, name: str, pid: int = ROUTER_PID, tid: int = 0,
-             args: Optional[dict] = None) -> _Span:
+             args: Optional[dict] = None):
         """Nestable duration span (no-op on unsampled waves)."""
+        if self._annotation is not None:
+            return self._annotation(PROFILER_PREFIX + name, **(args or {}))
         if not self._sampled:
             return _NULL_SPAN
         return _Span(self, name, pid, tid, args)
@@ -125,6 +145,10 @@ class SpanTracer:
                 args: Optional[dict] = None):
         """Point event (drops, retractions, churn) — always recorded,
         independent of wave sampling."""
+        if self._annotation is not None:
+            with self._annotation(PROFILER_PREFIX + name, **(args or {})):
+                pass
+            return
         if len(self.events) >= self.max_events:
             return
         self._ts += 1
@@ -134,31 +158,23 @@ class SpanTracer:
             ev["args"] = args
         self.events.append(ev)
 
-    def shard_mark(self, s: int, name: str, args: Optional[dict] = None):
-        """Per-shard-worker event on the shard's own pid track (the
-        parent emits on the worker's behalf — worker processes cannot
-        append to this list)."""
-        pid = shard_pid(s)
-        if pid not in self._named_pids:
-            self.process_name(pid, f"prefix-shard-{s}")
-        if not self._sampled:
-            return
-        self._emit("i", name, pid, 0, args)
-
     def process_name(self, pid: int, name: str):
-        self._named_pids[pid] = name
         self.events.append({"name": "process_name", "ph": "M",
                             "ts": 0, "pid": pid, "tid": 0,
                             "args": {"name": name}})
 
     # ---- export -------------------------------------------------------
     def to_json(self) -> dict:
+        if self._annotation is not None:
+            raise RuntimeError("a profiler-clock tracer keeps no events: "
+                               "the profiler writes its trace")
         return {"traceEvents": list(self.events),
                 "displayTimeUnit": "ms"}
 
     def export(self, path: str):
+        doc = self.to_json()
         with open(path, "w") as fh:
-            json.dump(self.to_json(), fh, sort_keys=True)
+            json.dump(doc, fh, sort_keys=True)
 
 
 # ---------------------------------------------------------------------------

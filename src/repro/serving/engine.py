@@ -15,6 +15,19 @@ times of the JAX computations, giving honest relative TTFT/TPOT between
 policies.  Each engine may be pinned to a device (its params and cache
 live there); engines on one device share one copy of the params.
 
+``EngineCluster(..., obs=...)`` hands an observability bundle
+(``repro.obs``) to its router and its engines.  An engine step then
+records the span ``engine.step`` (iid, kind) around ``engine.admit``
+(slot zeroing, store lookup, fragment injection), ``engine.prefill``
+(dispatch to ``block_until_ready``), ``engine.fragment_save`` (the slot
+copied to the host and stored; bytes) and ``engine.decode`` (dispatch to
+the tokens on the host; bs), and the counters
+``engine.fragment_d2h_bytes``/``engine.fragment_h2d_bytes`` (fragment
+leaves' bytes) and ``engine.predicted_hit_tokens``/
+``engine.reused_tokens`` (the router's hit for an admitted request and
+the tokens the engine took from its store).  With ``obs=None`` none of
+it runs (Contract 5).
+
 Encoder-decoder archs (whisper) are not served by this engine (the
 cluster simulator covers their scheduling); everything decoder-only —
 dense, MoE, SSM, hybrid — works.
@@ -22,6 +35,7 @@ dense, MoE, SSM, hybrid — works.
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import heapq
 import itertools
@@ -67,6 +81,13 @@ def _zero_slot(cache, b: int):
     sub = _slice_slot(cache, b)
     zeroed = jax.tree.map(jnp.zeros_like, sub)
     return _write_slot(cache, zeroed, b)
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+
+
+_NULL_CTX = contextlib.nullcontext()
 
 
 class PrefixStore:
@@ -156,9 +177,10 @@ class InstanceEngine:
     def __init__(self, model: Model, params, iid: int = 0,
                  max_batch: int = 8, max_len: int = 512,
                  chunk_tokens: int = 128, block_size: int = 16,
-                 prefix_capacity: int = 64, device=None):
+                 prefix_capacity: int = 64, device=None, obs=None):
         """``device``: where this engine's params and KV cache live
-        (``None``: JAX's default device, params left where they are)."""
+        (``None``: JAX's default device, params left where they are);
+        ``obs``: an observability bundle, or ``None`` (off)."""
         assert not model.cfg.is_encdec, "enc-dec not served by this engine"
         self.model = model
         self.device = device
@@ -172,6 +194,9 @@ class InstanceEngine:
         self.cache = model.init_cache(max_batch, max_len)
         if device is not None:
             self.cache = jax.device_put(self.cache, device)
+        #: bytes of one slot of the cache: a stored fragment's size
+        self.slot_bytes = _nbytes(self.cache) // max_batch
+        self.obs = obs
         self.prefix_store = PrefixStore(
             prefix_capacity,
             partial=all(k == "attn" for k in model.cfg.block_pattern))
@@ -189,16 +214,22 @@ class InstanceEngine:
         return bool(self.waiting) or bool(self.running)
 
     # ------------------------------------------------------------------
-    def _try_admit(self):
+    def _head(self):
+        """The queue's head if this step can prefill it (it holds a slot,
+        or one is free), else ``None``."""
         if not self.waiting or not self.free_slots:
             return None
-        seq = self.waiting[0]
-        if seq.slot >= 0:
-            return seq
+        return self.waiting[0]
+
+    def _admit(self, seq: _Seq, reg):
+        """Give ``seq`` a zeroed slot; on a prefix-store hit inject the
+        fragment and skip its compute."""
         slot = self.free_slots.pop(0)
         seq.slot = slot
         self.cache = _zero_slot(self.cache, slot)
-        # prefix-cache hit: inject fragment, skip its compute
+        if reg is not None:
+            # the router's prediction, before a store hit overwrites it
+            reg.inc("engine.predicted_hit_tokens", seq.req.hit_tokens)
         blocks = tuple(tokens_to_blocks(seq.tokens.tolist(),
                                         self.block_size))
         key, frag, length = self.prefix_store.lookup(blocks)
@@ -210,28 +241,53 @@ class InstanceEngine:
                 self.cache = _write_slot(self.cache, frag, slot)
                 seq.pos = seq.reused = usable
                 seq.req.hit_tokens = usable
-        return seq
+                if reg is not None:
+                    reg.inc("engine.fragment_h2d_bytes", _nbytes(frag))
+        if reg is not None:
+            reg.inc("engine.reused_tokens", seq.reused)
 
     def step(self) -> Dict:
         """One engine step: a prefill chunk (head-of-queue) OR a batched
         decode step for all running slots.  Returns events + wall time."""
         t0 = time.perf_counter()
+        seq = self._head()
+        obs = self.obs
+        if obs is None or obs.tracer is None:
+            events = self._step(seq, obs)
+        else:
+            kind = ("prefill" if seq is not None
+                    else "decode" if self.running else "idle")
+            with obs.tracer.span("engine.step",
+                                 args={"iid": self.iid, "kind": kind}):
+                events = self._step(seq, obs)
+        events["wall"] = time.perf_counter() - t0
+        return events
+
+    def _step(self, seq: Optional[_Seq], obs) -> Dict:
+        tr = obs.tracer if obs is not None else None
+        reg = obs.registry if obs is not None else None
         events = {"first": [], "finished": [], "kind": "idle",
                   "prefill_tokens": 0, "decode_bs": 0}
-        seq = self._try_admit()
-        if seq is not None and not seq.prefill_done:
+        if seq is not None:
             events["kind"] = "prefill"
+            if seq.slot < 0:
+                with (tr.span("engine.admit", args={"rid": seq.req.rid})
+                      if tr is not None else _NULL_CTX):
+                    self._admit(seq, reg)
             n = min(self.chunk, len(seq.tokens) - seq.pos,
                     self.max_len - seq.pos)
-            toks = jnp.asarray(
-                seq.tokens[seq.pos: seq.pos + n][None], jnp.int32)
-            positions = jnp.arange(seq.pos, seq.pos + n,
-                                   dtype=jnp.int32)[None]
-            cache_len = jnp.asarray(seq.pos, jnp.int32)
-            logits, self.cache = prefill_slot(
-                self.model, self.params, self.cache, toks, positions,
-                cache_len, seq.slot)
-            logits.block_until_ready()
+            with (tr.span("engine.prefill",
+                          args={"rid": seq.req.rid, "tokens": n})
+                  if tr is not None else _NULL_CTX):
+                toks = jnp.asarray(
+                    seq.tokens[seq.pos: seq.pos + n][None], jnp.int32)
+                positions = jnp.arange(seq.pos, seq.pos + n,
+                                       dtype=jnp.int32)[None]
+                cache_len = jnp.asarray(seq.pos, jnp.int32)
+                logits, self.cache = prefill_slot(
+                    self.model, self.params, self.cache, toks, positions,
+                    cache_len, seq.slot)
+                logits.block_until_ready()
             events["prefill_tokens"] = n
             seq.pos += n
             if seq.pos >= min(len(seq.tokens), self.max_len):
@@ -249,20 +305,28 @@ class InstanceEngine:
                 blocks = tuple(tokens_to_blocks(
                     seq.tokens.tolist(), self.block_size))
                 if blocks:
-                    frag = jax.tree.map(np.asarray,
-                                        _slice_slot(self.cache, seq.slot))
-                    self.prefix_store.insert(
-                        blocks, frag, len(blocks) * self.block_size)
+                    with (tr.span("engine.fragment_save",
+                                  args={"rid": seq.req.rid,
+                                        "bytes": self.slot_bytes})
+                          if tr is not None else _NULL_CTX):
+                        frag = jax.tree.map(
+                            np.asarray, _slice_slot(self.cache, seq.slot))
+                        self.prefix_store.insert(
+                            blocks, frag, len(blocks) * self.block_size)
+                    if reg is not None:
+                        reg.inc("engine.fragment_d2h_bytes", _nbytes(frag))
                 if seq.generated >= seq.req.output_len:
                     self._finish(seq, events)
         elif self.running:
             events["kind"] = "decode"
             events["decode_bs"] = len(self.running)
-            toks = jnp.asarray(self._last_tokens[:, None], jnp.int32)
-            pos = jnp.asarray(self._pos, jnp.int32)
-            nxt, self.cache = decode_step(self.model, self.params,
-                                          self.cache, toks, pos)
-            nxt = np.asarray(nxt)
+            with (tr.span("engine.decode", args={"bs": len(self.running)})
+                  if tr is not None else _NULL_CTX):
+                toks = jnp.asarray(self._last_tokens[:, None], jnp.int32)
+                pos = jnp.asarray(self._pos, jnp.int32)
+                nxt, self.cache = decode_step(self.model, self.params,
+                                              self.cache, toks, pos)
+                nxt = np.asarray(nxt)
             for slot, seq in list(self.running.items()):
                 tok = int(nxt[slot])
                 seq.out_tokens.append(tok)
@@ -272,7 +336,6 @@ class InstanceEngine:
                 if seq.generated >= seq.req.output_len or \
                         self._pos[slot] >= self.max_len - 1:
                     self._finish(seq, events)
-        events["wall"] = time.perf_counter() - t0
         return events
 
     def _finish(self, seq: _Seq, events):
@@ -299,21 +362,25 @@ class EngineCluster:
 
     def __init__(self, n_instances: int, model: Model, params, policy,
                  block_size: int = 16, kv_capacity_tokens: int = 1 << 62,
-                 devices: Optional[Sequence] = None, **engine_kw):
+                 devices: Optional[Sequence] = None, obs=None,
+                 **engine_kw):
         """``devices``: engine ``i`` runs on ``devices[i % len(devices)]``
         (``None``: every engine on JAX's default device).  The params are
-        copied once to each distinct device."""
+        copied once to each distinct device.  ``obs`` (``repro.obs``)
+        goes to the router and every engine; ``None`` is off."""
         devs = [devices[i % len(devices)] if devices else None
                 for i in range(n_instances)]
         placed = {d: params if d is None else jax.device_put(params, d)
                   for d in set(devs)}
         self.engines = [InstanceEngine(model, placed[d], iid=i, device=d,
-                                       block_size=block_size, **engine_kw)
+                                       block_size=block_size, obs=obs,
+                                       **engine_kw)
                         for i, d in enumerate(devs)]
         exact_only = not model.cfg.has_kv_blocks
         self.router = Router(policy, n_instances,
                              kv_capacity_tokens=kv_capacity_tokens,
-                             block_size=block_size, exact_only=exact_only)
+                             block_size=block_size, exact_only=exact_only,
+                             obs=obs)
         self.block_size = block_size
         self.served: Dict[int, _Seq] = {}   # rid -> finished sequence
 

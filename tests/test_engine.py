@@ -170,3 +170,62 @@ def test_cluster_places_engines_on_devices(setup):
             assert leaf.devices() == {dev}
     a, b = (jax.tree.leaves(e.params)[0] for e in cluster.engines)
     assert a.unsafe_buffer_pointer() == b.unsafe_buffer_pointer()
+
+
+def test_cluster_obs_serves_identical_tokens_and_counts_fragments(
+        setup, tmp_path, monkeypatch):
+    """A cluster with a profiler-clock bundle routes and serves exactly
+    as one without; each fragment moved is one slot of the cache by its
+    shapes, every prefill and decode step is spanned inside its engine
+    step, and the engines' reuse is counted beside the router's
+    prediction."""
+    import itertools
+    import types
+    from test_obs import inside, profiled_spans
+    from repro.obs import make_obs
+    from repro.serving import engine as engine_mod
+    cfg, m, params = setup
+    # the cluster's clock advances by each step's measured wall, which
+    # would reorder events between runs: every step reads 5 ms here
+    ticks = itertools.count()
+    monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(ticks) * 0.005))
+    kw = dict(block_size=16, max_batch=4, max_len=160, chunk_tokens=160)
+    arrivals = _arrivals(n=10, seed=2)
+
+    def serve(obs):
+        cluster = EngineCluster(2, m, params, LMetricPolicy(), obs=obs,
+                                **kw)
+        done = cluster.run(arrivals)
+        return cluster, {r.rid: (r.sched_to, r.hit_tokens,
+                                 list(cluster.served[r.rid].out_tokens))
+                         for r in done}
+
+    _, base = serve(None)
+    obs = make_obs(metrics=True, trace="profiler")
+    got = []
+    spans = profiled_spans(tmp_path / "p", lambda: got.append(serve(obs)))
+    cluster, served = got[0]
+    assert served == base
+    # one slot of the cache, from the shapes alone
+    shapes = jax.eval_shape(lambda: m.init_cache(1, kw["max_len"]))
+    slot = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(shapes))
+    assert all(e.slot_bytes == slot for e in cluster.engines)
+    seqs = list(cluster.served.values())
+    c = obs.registry.counters
+    assert c["engine.fragment_d2h_bytes"] == slot * len(seqs)
+    injected = sum(1 for s in seqs if s.reused)
+    assert injected > 0
+    assert c["engine.fragment_h2d_bytes"] == slot * injected
+    assert c["engine.reused_tokens"] == sum(s.reused for s in seqs)
+    assert c["engine.predicted_hit_tokens"] > 0
+    saves = [a for name, _, _, a in spans
+             if name == "repro.engine.fragment_save"]
+    assert len(saves) == len(seqs)
+    assert all(a["bytes"] == slot for a in saves)
+    for child in ("admit", "prefill", "fragment_save", "decode"):
+        assert inside(spans, f"repro.engine.{child}", "repro.engine.step")
+    kinds = {a["kind"] for name, _, _, a in spans
+             if name == "repro.engine.step"}
+    assert kinds == {"prefill", "decode"}

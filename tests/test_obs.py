@@ -4,11 +4,14 @@
    count/sum/max past capacity, snapshots are sorted/JSON-able, merges
    are deterministic (counters sum, gauges max), and the fixed-slot
    worker block folds into per-shard scoped counters idempotently.
-2. **Trace round-trip** — spans/instants/marks emit Chrome trace-event
+2. **Trace round-trip** — spans/instants emit Chrome trace-event
    JSON that survives export → parse → validation (balanced B/E
    nesting, monotonic virtual timestamps, pid/tid mapping with named
    process tracks), is byte-identical across identical runs, and
-   respects the every-Nth-wave sampling knob.
+   respects the every-Nth-wave sampling knob; on the profiler clock the
+   spans land, nested and with their args, in a ``jax.profiler`` trace,
+   every wave, and the mid-wave eviction fallback is spanned and
+   counted exactly.
 3. **Contract 5 (disabled-mode identity)** — with ``obs=None`` (the
    default) AND with a fully-enabled bundle, ``route_batch`` decisions
    stay bit-identical to the frozen scalar reference across
@@ -35,7 +38,7 @@ from repro.obs import make_obs
 from repro.obs.registry import (N_WORKER_SLOTS, WORKER_SLOTS, Histogram,
                                 MetricsRegistry, merge_snapshots)
 from repro.obs.trace import (ROUTER_PID, SpanTracer, load_trace,
-                             shard_pid, validate_events)
+                             validate_events)
 from repro.workloads.sessions import make_mixed_sessions
 from repro.workloads.traces import make_hotspot_trace
 
@@ -104,8 +107,7 @@ def _emit_demo(tracer):
     tracer.wave_tick()
     with tracer.span("wave", args={"k": 3}):
         with tracer.span("walk"):
-            tracer.shard_mark(0, "walk", args={"walks": 1})
-            tracer.shard_mark(1, "walk", args={"walks": 1})
+            pass
         with tracer.span("score"):
             tracer.instant("spec.submit", args={"k": 2})
         with tracer.span("commit"):
@@ -126,15 +128,13 @@ def test_trace_round_trip_schema(tmp_path):
     assert names == [("B", "wave"), ("B", "walk"), ("E", "walk"),
                      ("B", "score"), ("E", "score"), ("B", "commit"),
                      ("E", "commit"), ("E", "wave")]
-    # pid/tid mapping: the shard marks land on their own named tracks
+    # pid/tid mapping: one named router track carries every event
     meta = {e["pid"]: e["args"]["name"] for e in events
             if e["ph"] == "M" and e["name"] == "process_name"}
-    assert meta[ROUTER_PID] == "router"
-    assert meta[shard_pid(0)] == "prefix-shard-0"
-    assert meta[shard_pid(1)] == "prefix-shard-1"
-    marks = [e for e in events if e["ph"] == "i"
-             and e["pid"] == shard_pid(0)]
-    assert len(marks) == 1 and marks[0]["name"] == "walk"
+    assert meta == {ROUTER_PID: "router"}
+    instants = [(e["pid"], e["name"]) for e in events if e["ph"] == "i"]
+    assert instants == [(ROUTER_PID, "spec.submit"),
+                        (ROUTER_PID, "churn.fail")]
     # virtual clock: timestamps are monotonic and follow set_time
     ts = [e["ts"] for e in events if e["ph"] != "M"]
     assert ts == sorted(ts) and ts[0] >= 1_000_000
@@ -165,6 +165,63 @@ def test_sampling_knob_bounds_span_volume():
     drops = [e for e in tr.events if e["name"] == "drop"]
     assert len(waves) == 2 * 2   # waves 0 and 4, B+E each
     assert len(drops) == 8
+
+
+def profiled_spans(log_dir, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace written to ``log_dir``;
+    return its ``repro.*`` host events as (name, start_ns, end_ns,
+    args) in start order."""
+    import glob
+    import jax
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    out = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+           for plane in data.planes if plane.name.startswith("/host:")
+           for line in plane.lines for e in line.events
+           if e.name.startswith("repro.")]
+    return sorted(out, key=lambda e: e[1])
+
+
+def inside(spans, child, parent):
+    """Every ``child`` span lies within some ``parent`` span."""
+    outer = [(lo, hi) for name, lo, hi, _ in spans if name == parent]
+    kids = [(lo, hi) for name, lo, hi, _ in spans if name == child]
+    return bool(kids) and all(any(a <= lo and hi <= b for a, b in outer)
+                              for lo, hi in kids)
+
+
+def test_profiler_clock_spans_and_instants(tmp_path):
+    """The profiler clock records every wave (no sampling) as
+    ``repro.*`` annotations with the span's args, instants as zero-length
+    ones, keeps no events of its own and refuses to export."""
+    obs = make_obs(metrics=False, trace="profiler", sample_every=4)
+    tr = obs.tracer
+
+    def emit():
+        for w in range(3):
+            assert tr.wave_tick()
+            with tr.span("wave", args={"wave": w, "k": 2}):
+                with tr.span("walk"):
+                    pass
+            tr.instant("drop", args={"rid": w})
+
+    spans = profiled_spans(tmp_path / "p", emit)
+    waves = [a for name, _, _, a in spans if name == "repro.wave"]
+    assert waves == [{"wave": w, "k": 2} for w in range(3)]
+    assert inside(spans, "repro.walk", "repro.wave")
+    assert [a for name, _, _, a in spans if name == "repro.drop"] == \
+        [{"rid": w} for w in range(3)]
+    assert tr.events == []
+    with pytest.raises(RuntimeError, match="profiler"):
+        tr.export(str(tmp_path / "t.json"))
+    assert not (tmp_path / "t.json").exists()
+    with pytest.raises(ValueError, match="trace"):
+        make_obs(trace="wall")
 
 
 # ---------------------------------------------------------------------------
@@ -257,6 +314,61 @@ def test_trace_byte_identical_across_runs(trace):
         _decisions(sub, obs=obs, n_shards=2)
         docs.append(json.dumps(obs.tracer.to_json(), sort_keys=True))
     assert docs[0] == docs[1]
+
+
+def _fallback_run(reqs, obs, monkeypatch):
+    """Route ``reqs`` on a fleet whose caches evict, counting from
+    outside every request the commit stage hands to the sequential
+    re-route; returns (decisions, fallback requests, fallback waves)."""
+    import repro.core.router as router_mod
+    orig = router_mod.commit_wave_plan
+    seen = [0, 0]
+
+    def counted(factory, wave, commit, fallback):
+        def tail(rest):
+            seen[0] += len(rest)
+            seen[1] += 1
+            return fallback(rest)
+        return orig(factory, wave, commit, tail)
+
+    monkeypatch.setattr(router_mod, "commit_wave_plan", counted)
+    router = Router(make_policy("lmetric"), N_INST,
+                    kv_capacity_tokens=20_000, obs=obs)
+    try:
+        return _drive(router, reqs), seen[0], seen[1]
+    finally:
+        router.close()
+        monkeypatch.setattr(router_mod, "commit_wave_plan", orig)
+
+
+def test_fallback_counted_and_spanned_on_profiler_clock(trace, tmp_path,
+                                                        monkeypatch):
+    """With caches that evict mid-wave, the router with a profiler-clock
+    bundle decides exactly as with none; the registry's fallback
+    counters equal an outside count of the re-routed tail, and each
+    fallback is one ``repro.commit.fallback`` span inside a commit,
+    carrying the tail's length."""
+    base, n_base, _ = _fallback_run(trace, None, monkeypatch)
+    assert n_base > 0, "the fleet's caches never evicted mid-wave"
+    obs = make_obs(metrics=True, trace="profiler")
+    got = []
+    spans = profiled_spans(tmp_path / "p", lambda: got.append(
+        _fallback_run(trace, obs, monkeypatch)))
+    decisions, n_req, n_waves = got[0]
+    assert decisions == base
+    c = obs.registry.counters
+    assert c["pipeline.fallback_requests"] == n_req == n_base
+    assert c["pipeline.fallback_waves"] == n_waves
+    ks = [a["k"] for name, _, _, a in spans
+          if name == "repro.commit.fallback"]
+    assert len(ks) == n_waves and sum(ks) == n_req
+    assert inside(spans, "repro.commit.fallback", "repro.commit")
+    for stage in ("walk", "score", "commit"):
+        assert inside(spans, f"repro.{stage}", "repro.wave")
+    for part in ("submit", "collect"):
+        assert inside(spans, f"repro.score.{part}", "repro.score")
+    waves = [a for name, _, _, a in spans if name == "repro.wave"]
+    assert [w["wave"] for w in waves] == list(range(len(waves)))
 
 
 # ---------------------------------------------------------------------------
