@@ -430,27 +430,51 @@ class AggregatedPrefixIndex:
         (radix eviction removes leaves only, so chains stay prefix-closed
         and the subset invariant holds).
         """
+        self.remove_tail(iid, path, 1)
+
+    def remove_tail(self, iid: int, path: Sequence[int], m: int):
+        """Instance ``iid`` evicted the deepest ``m`` blocks of ``path``
+        bottom-up (one ``RadixKVIndex.on_evict`` run): the same work as
+        ``remove_leaf`` on ``path[:len(path) - j]`` for ``j`` in
+        ``range(m)``, in that order, with one descent instead of ``m``.
+
+        A node a ``remove_leaf`` descent would not reach (missing from
+        the index, or pruned by the previous step) is skipped, as that
+        call would return early.
+        """
         kids = self._kids
-        node = 0
+        node = depth = 0
         for b in path:
-            node = kids[node].get(b)
-            if node is None:
-                return
+            child = kids[node].get(b)
+            if child is None:
+                break
+            node = child
+            depth += 1
+        stop = max(len(path) - m, 0)      # depth of the deepest survivor
+        if depth <= stop:
+            return
         w = iid >> 6
         mbit = 1 << (iid & 63)
-        v = self._masks.item(node, w)
-        if v & mbit:
-            self._masks[node, w] = np.uint64(v & ~mbit)
-            self._pop[node] -= 1
-            if self._dig_on:
-                self._dig = (self._dig - ((self._chash[node]
-                                           ^ _ihash(iid))
-                                          * _PHI & _M64)) & _M64
-                self._bits -= 1
-        # prune the freed tail: no instance holds it, nothing hangs off
-        pop = self._pop
-        while node and not pop[node] and not kids[node]:
-            node = self._free_node(node)
+        masks, pop, parent = self._masks, self._pop, self._parent
+        ih = _ihash(iid) if self._dig_on else 0
+        while depth > stop:
+            v = masks.item(node, w)
+            if v & mbit:
+                masks[node, w] = np.uint64(v & ~mbit)
+                pop[node] -= 1
+                if self._dig_on:
+                    self._dig = (self._dig - ((self._chash[node] ^ ih)
+                                              * _PHI & _M64)) & _M64
+                    self._bits -= 1
+            if pop[node] or kids[node]:
+                node = parent[node]
+                depth -= 1
+            else:
+                # prune the freed tail: no instance holds it, nothing
+                # hangs off; the run's nodes it frees are skipped
+                while node and not pop[node] and not kids[node]:
+                    node = self._free_node(node)
+                    depth -= 1
 
     def remove_instance(self, iid: int):
         """Instance ``iid`` cleared its whole cache: one vectorized
@@ -1045,8 +1069,11 @@ class IndicatorFactory:
         self._dirty = np.ones(self.n_shards, dtype=bool)
         self._dev_shards = [None] * self.n_shards
         self._dev = None
-        # mid-wave plan invalidation signal for Router.route_batch
+        # mid-wave plan invalidation signal for Router.route_batch;
+        # evict_runs counts on_evict calls (evictions / evict_runs is
+        # the leaves per run)
         self.evictions = 0
+        self.evict_runs = 0
         # host-walk telemetry: aggregated-index walk time / walk count
         # (per unique prompt), surfaced by Router.mean_walk_us
         self.walk_ns = 0
@@ -1083,8 +1110,8 @@ class IndicatorFactory:
             if self._agg is not None:
                 kv.on_insert = (lambda blocks, _i=i:
                                 self._on_insert(_i, blocks))
-                kv.on_evict = (lambda path, _i=i:
-                               self._on_evict(_i, path))
+                kv.on_evict = (lambda path, m, _i=i:
+                               self._on_evict(_i, path, m))
                 kv.on_clear = (lambda _i=i: self._on_clear(_i))
             self.instances.append(InstanceState(i, self, kv))
         self._wire_agg()
@@ -1142,12 +1169,13 @@ class IndicatorFactory:
         if self._capture is not None:
             self._capture.append((iid, blocks))
 
-    def _on_evict(self, iid: int, path):
-        self.evictions += 1
+    def _on_evict(self, iid: int, path, m: int):
+        self.evictions += m
+        self.evict_runs += 1
         try:
-            self._agg.remove_leaf(iid, path)
+            self._agg.remove_tail(iid, path, m)
         except (RuntimeError, OSError) as e:
-            self._mutate_recover(e, "remove_leaf", iid, path)
+            self._mutate_recover(e, "remove_tail", iid, path, m)
 
     def _on_clear(self, iid: int):
         self.evictions += 1

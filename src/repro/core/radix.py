@@ -10,16 +10,22 @@ identical blocks under different prefixes get distinct ids — prefix
 semantics preserved).
 
 Eviction is LRU over leaf blocks under a token-capacity budget, matching
-finite per-instance KV$ space.  ``exact_only`` supports the recurrent
-families (DESIGN.md §Arch-applicability): a recurrent-state snapshot is
-reusable only on an exact full-prefix boundary, so partial prefix credit
-is disallowed.
+finite per-instance KV$ space.  The tree keeps its leaf set as it
+changes, so an eviction heapifies the leaves without walking the tree.
+``exact_only`` supports the recurrent families (DESIGN.md
+§Arch-applicability): a recurrent-state snapshot is reusable only on an
+exact full-prefix boundary, so partial prefix credit is disallowed.
 
 Coherence callbacks: ``on_insert(blocks)`` fires after every ``insert``
-and ``on_evict(path)`` after every leaf eviction (``path`` is the full
-root→leaf key chain).  ``IndicatorFactory`` uses them to keep its
-aggregated cross-instance prefix index in sync, so any caller may mutate
-``inst.kv`` directly without desynchronising vectorized hit lookups.
+and ``on_evict(path, m)`` once per *run* of evictions — a chain tail
+evicted bottom-up, each leaf's parent the next leaf popped.  ``path`` is
+the full root→leaf key chain of the run's deepest leaf and ``m`` the
+number of blocks the run took off its end, so the run's leaves are
+``path[:len(path) - j]`` for ``j`` in ``range(m)``, deepest first; the
+callback fires once the run has left the tree.  ``IndicatorFactory``
+uses them to keep its aggregated cross-instance prefix index in sync, so
+any caller may mutate ``inst.kv`` directly without desynchronising
+vectorized hit lookups.
 """
 from __future__ import annotations
 
@@ -60,6 +66,8 @@ class RadixKVIndex:
         self.root = _Node(None, None)
         self._clock = itertools.count(1)
         self._n_blocks = 0
+        # every non-root node without children, kept as the tree changes
+        self._leaves = set()
         # coherence hooks (see module docstring); None = disabled
         self.on_insert = None
         self.on_evict = None
@@ -105,12 +113,16 @@ class RadixKVIndex:
         for b in blocks:
             child = node.children.get(b)
             if child is None:
+                if not added and not node.children:
+                    self._leaves.discard(node)   # stops being a leaf
                 child = _Node(node, b)
                 node.children[b] = child
                 self._n_blocks += 1
                 added += 1
             child.last_use = now
             node = child
+        if added:
+            self._leaves.add(node)  # new nodes form one chain below it
         if node is not self.root:
             node.terminal = True    # snapshot saved at this boundary
         if self.on_insert is not None and blocks:
@@ -121,33 +133,40 @@ class RadixKVIndex:
 
     # ------------------------------------------------------------------
     def _evict_to_capacity(self):
-        # collect leaves once, heapify by last_use, pop until under budget;
-        # promote parents that become leaves.
-        leaves = []
-        stack = [self.root]
-        while stack:
-            n = stack.pop()
-            if n is not self.root and not n.children:
-                leaves.append((n.last_use, id(n), n))
-            stack.extend(n.children.values())
-        heapq.heapify(leaves)
-        while self.tokens_stored > self.capacity_tokens and leaves:
-            _, _, leaf = heapq.heappop(leaves)
-            if leaf.children or leaf.parent is None:
-                continue  # stale entry
-            parent = leaf.parent
-            if self.on_evict is not None:
-                path, n = [], leaf
-                while n.parent is not None:
-                    path.append(n.key)
-                    n = n.parent
-                path.reverse()
-                self.on_evict(path)
+        # heapify the kept leaves by last_use, pop until under budget,
+        # promoting parents that become leaves.  Nodes sharing a stamp
+        # lie on one root path, so at most one of them is a leaf at a
+        # time: leaf stamps are unique and the pop order is total.
+        leaves = self._leaves
+        heap = [(n.last_use, id(n), n) for n in leaves]
+        heapq.heapify(heap)
+        keep = self.capacity_tokens // self.block_size
+        on_evict = self.on_evict
+        root = self.root
+        path, m, nxt = None, 0, None
+        while self._n_blocks > keep and heap:
+            _, _, leaf = heapq.heappop(heap)
+            if on_evict is not None:
+                if leaf is not nxt:         # a new run starts here
+                    if m:
+                        on_evict(path, m)
+                    path, n = [], leaf
+                    while n is not root:
+                        path.append(n.key)
+                        n = n.parent
+                    path.reverse()
+                    m = 0
+                m += 1
+            parent = nxt = leaf.parent
             del parent.children[leaf.key]
             leaf.parent = None
+            leaves.discard(leaf)
             self._n_blocks -= 1
-            if parent is not self.root and not parent.children:
-                heapq.heappush(leaves, (parent.last_use, id(parent), parent))
+            if parent is not root and not parent.children:
+                leaves.add(parent)
+                heapq.heappush(heap, (parent.last_use, id(parent), parent))
+        if m:
+            on_evict(path, m)
 
     def evict_tokens(self, n_tokens: int):
         """Force-evict at least n_tokens (LRU leaves)."""
@@ -187,5 +206,6 @@ class RadixKVIndex:
     def clear(self):
         self.root = _Node(None, None)
         self._n_blocks = 0
+        self._leaves = set()
         if self.on_clear is not None:
             self.on_clear()

@@ -70,11 +70,11 @@ class ShardedPrefixIndex:
 
     Drop-in for ``AggregatedPrefixIndex`` everywhere the factory uses
     it: same mutation protocol (``add`` / ``remove_leaf`` /
-    ``remove_instance`` with **global** instance ids), same query
-    surface (``match_depths`` / ``match_depths_many`` returning
-    full-width ``(n,)`` / ``(k, n)`` depth arrays).  Mutations route to
-    the owning shard only; queries fan out to all shards, each writing
-    its own column slice of the output.
+    ``remove_tail`` / ``remove_instance`` with **global** instance
+    ids), same query surface (``match_depths`` / ``match_depths_many``
+    returning full-width ``(n,)`` / ``(k, n)`` depth arrays).
+    Mutations route to the owning shard only; queries fan out to all
+    shards, each writing its own column slice of the output.
 
     ``backend`` selects the execution strategy (``"serial"`` /
     ``"thread"`` / ``"process"`` or a prebuilt ``ShardBackend``);
@@ -136,6 +136,15 @@ class ShardedPrefixIndex:
     def remove_leaf(self, iid: int, path: Sequence[int]):
         s, li = self._local(iid)
         self.backend.mutate(s, "remove_leaf", li, path)
+
+    def remove_tail(self, iid: int, path: Sequence[int], m: int):
+        """One eviction run as ``m`` owner-routed ``remove_leaf``
+        mutations, deepest first: the worker command set and the
+        per-shard mutation ordinals fault plans key on stay per leaf."""
+        s, li = self._local(iid)
+        n = len(path)
+        for j in range(m):
+            self.backend.mutate(s, "remove_leaf", li, path[:n - j])
 
     def remove_instance(self, iid: int):
         s, li = self._local(iid)

@@ -232,19 +232,21 @@ def ingest_router(reg: MetricsRegistry, router) -> MetricsRegistry:
     """Re-home the router's legacy telemetry onto ``reg``.
 
     Reads every pre-registry accumulator — the factory's
-    ``walk_ns``/``walks``/``degraded_rebuilds``/``evictions``, the
-    pipeline's per-stage ns totals and speculation counters, the shard
-    backend's fixed-slot worker block — and mirrors them as scoped
-    counters via ``counter_set`` (source-owned counts: ingestion is
-    idempotent, never double-counting).  ``Router.metrics_snapshot``
-    calls this; ``walk_telemetry``/``stage_stats`` remain as
-    compatibility shims over the same underlying accumulators.
+    ``walk_ns``/``walks``/``degraded_rebuilds``/``evictions``/
+    ``evict_runs``, the pipeline's per-stage ns totals and speculation
+    counters, the shard backend's fixed-slot worker block — and mirrors
+    them as scoped counters via ``counter_set`` (source-owned counts:
+    ingestion is idempotent, never double-counting).
+    ``Router.metrics_snapshot`` calls this; ``walk_telemetry``/
+    ``stage_stats`` remain as compatibility shims over the same
+    underlying accumulators.
     """
     f = router.factory
     reg.counter_set("index.walk_ns", f.walk_ns)
     reg.counter_set("index.walks", f.walks)
     reg.counter_set("index.degraded_rebuilds", f.degraded_rebuilds)
     reg.counter_set("index.evictions", f.evictions)
+    reg.counter_set("index.evict_runs", f.evict_runs)
     p = router.pipeline
     reg.counter_set("pipeline.walk_ns", p.walk_ns)
     reg.counter_set("pipeline.score_ns", p.score_ns)

@@ -431,6 +431,28 @@ def test_metrics_snapshot_mirrors_legacy_telemetry(trace):
         router.close()
 
 
+@pytest.mark.parametrize("capacity", [2_000, 1 << 62])
+def test_metrics_snapshot_counts_eviction_runs(trace, capacity):
+    """``index.evict_runs`` counts ``on_evict`` calls, one per run of
+    leaves evicted up a chain tail, beside ``index.evictions``, which
+    counts the leaves: a capacity-bound router evicts in runs, an
+    unbounded one never evicts."""
+    router = Router(make_policy("lmetric"), N_INST,
+                    kv_capacity_tokens=capacity)
+    try:
+        _drive(router, trace[:200])
+        snap = router.metrics_snapshot()["counters"]
+        f = router.factory
+        assert snap["index.evictions"] == f.evictions
+        assert snap["index.evict_runs"] == f.evict_runs
+        if capacity < 1 << 62:
+            assert snap["index.evictions"] >= snap["index.evict_runs"] > 0
+        else:
+            assert snap["index.evictions"] == snap["index.evict_runs"] == 0
+    finally:
+        router.close()
+
+
 @pytest.mark.hetero
 def test_provenance_round_trip_hetero_fields(trace):
     """On a heterogeneous fleet every provenance record carries the

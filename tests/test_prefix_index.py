@@ -27,27 +27,86 @@ from repro.core.radix import RadixKVIndex
 B = 4  # block size for the driver trees
 
 
-class _Pair:
-    """New + reference index driven through one set of radix trees."""
+def _remove_leaf_ref(idx, iid, path):
+    """``AggregatedPrefixIndex.remove_leaf`` as it was before eviction
+    runs: one descent per leaf, then clear and prune — the reference
+    ``remove_tail`` must equal, leaf for leaf."""
+    from repro.core.indicators import _M64, _PHI, _ihash
+    kids = idx._kids
+    node = 0
+    for b in path:
+        node = kids[node].get(b)
+        if node is None:
+            return
+    w = iid >> 6
+    mbit = 1 << (iid & 63)
+    v = idx._masks.item(node, w)
+    if v & mbit:
+        idx._masks[node, w] = np.uint64(v & ~mbit)
+        idx._pop[node] -= 1
+        if idx._dig_on:
+            idx._dig = (idx._dig - ((idx._chash[node] ^ _ihash(iid))
+                                    * _PHI & _M64)) & _M64
+            idx._bits -= 1
+    while node and not idx._pop[node] and not kids[node]:
+        node = idx._free_node(node)
 
-    def __init__(self, n, capacity_tokens=10 ** 9, agg_capacity=2):
+
+def _assert_same_state(a, b):
+    """Two flat indexes hold the same rows: masks, popcounts, tree
+    links, free list and, once on, the incremental digest."""
+    assert a._top == b._top
+    top = a._top
+    assert np.array_equal(a._masks[:top], b._masks[:top])
+    for name in ("_pop", "_parent", "_live", "_key", "_kids"):
+        assert getattr(a, name)[:top] == getattr(b, name)[:top], name
+    assert a._free == b._free
+    assert a._dig_on == b._dig_on
+    if a._dig_on:
+        assert (a._dig, a._bits) == (b._dig, b._bits)
+        assert a.digest == b.digest == a.rescan_digest()
+
+
+class _Pair:
+    """New + reference index driven through one set of radix trees.
+
+    Each ``on_evict`` run goes to ``new`` as one ``remove_tail`` and,
+    expanded into its leaves, one removal per leaf to the bigint
+    reference and to ``leafwise``, a second flat index driven by the
+    per-leaf ``_remove_leaf_ref``, whose whole state ``check_state``
+    holds equal to ``new``'s."""
+
+    def __init__(self, n, capacity_tokens=10 ** 9, agg_capacity=2,
+                 digest=False):
         self.n = n
         # tiny initial capacity so growth + free-list recycling is
         # exercised by every scenario
         self.new = AggregatedPrefixIndex(n, capacity=agg_capacity)
+        self.leafwise = AggregatedPrefixIndex(n, capacity=agg_capacity)
         self.ref = AggregatedPrefixIndexRef(n)
+        if digest:
+            self.new.digest, self.leafwise.digest   # switch upkeep on
         self.kvs = []
         for i in range(n):
             kv = RadixKVIndex(block_size=B, capacity_tokens=capacity_tokens)
             kv.on_insert = (lambda blocks, _i=i: (
-                self.new.add(_i, blocks), self.ref.add(_i, blocks)))
-            kv.on_evict = (lambda path, _i=i: (
-                self.new.remove_leaf(_i, path),
-                self.ref.remove_leaf(_i, path)))
+                self.new.add(_i, blocks), self.leafwise.add(_i, blocks),
+                self.ref.add(_i, blocks)))
+            kv.on_evict = (lambda path, m, _i=i: self._evict(_i, path, m))
             kv.on_clear = (lambda _i=i: (
                 self.new.remove_instance(_i),
+                self.leafwise.remove_instance(_i),
                 self.ref.remove_instance(_i)))
             self.kvs.append(kv)
+
+    def _evict(self, iid, path, m):
+        self.new.remove_tail(iid, path, m)
+        for j in range(m):
+            _remove_leaf_ref(self.leafwise, iid, path[:len(path) - j])
+            self.ref.remove_leaf(iid, path[:len(path) - j])
+
+    def check_state(self):
+        _assert_same_state(self.new, self.leafwise)
 
     def check(self, probes):
         got = self.new.match_depths_many(probes)
@@ -71,15 +130,20 @@ class _Pair:
 
 
 def _chain_pool(rng, n_chains=48, alphabet=6, max_len=12):
-    """Chains with heavy prefix sharing (small alphabet → deep LCPs)."""
-    return [tuple(rng.randint(0, alphabet, rng.randint(1, max_len)))
+    """Chains with heavy prefix sharing (small alphabet → deep LCPs),
+    of Python ints as block ids are (the digest's chain hash needs
+    them)."""
+    return [tuple(rng.randint(0, alphabet, rng.randint(1, max_len))
+                  .tolist())
             for _ in range(n_chains)]
 
 
+@pytest.mark.parametrize("digest", [False, True])
 @pytest.mark.parametrize("n", [1, 3, 16, 63, 64, 65, 130, 256])
-def test_random_interleavings_match_reference(n):
+def test_random_interleavings_match_reference(n, digest):
     rng = np.random.RandomState(n)
-    pair = _Pair(n, capacity_tokens=15 * B)   # tight: constant eviction
+    # tight capacity: constant eviction
+    pair = _Pair(n, capacity_tokens=15 * B, digest=digest)
     pool = _chain_pool(rng)
     for step in range(300):
         op, i = rng.rand(), rng.randint(n)
@@ -89,6 +153,7 @@ def test_random_interleavings_match_reference(n):
             pair.kvs[i].evict_tokens(int(rng.randint(1, 8)) * B)
         elif op < 0.95:
             pair.kvs[i].clear()
+        pair.check_state()
         if step % 29 == 0:
             k = rng.randint(1, 9)
             probes = [pool[rng.randint(len(pool))] for _ in range(k)]
@@ -97,6 +162,45 @@ def test_random_interleavings_match_reference(n):
             pair.check(probes)
     pair.check(pool)
     pair.rebuild_matches(pool)
+
+
+def test_remove_tail_equals_leafwise_removal_off_protocol():
+    """``remove_tail`` (and ``remove_leaf``, its ``m == 1``) equals ``m``
+    per-leaf removals even where the index has drifted from the trees: paths missing their deepest
+    nodes, bits another mutation never set, ancestors the prune frees
+    mid-run, and bits flipped under a stale popcount."""
+    rng = np.random.RandomState(11)
+    n = 70
+    a = AggregatedPrefixIndex(n, capacity=2)
+    b = AggregatedPrefixIndex(n, capacity=2)
+    a.digest, b.digest                    # both paths keep the digest
+    for step in range(2000):
+        iid = rng.randint(n)
+        path = tuple(rng.randint(0, 4, rng.randint(1, 9)).tolist())
+        op = rng.rand()
+        if op < 0.45:
+            for ix in (a, b):
+                ix.add(iid, path)
+        elif op < 0.9:
+            m = rng.randint(1, len(path) + 1)
+            if m == 1 and rng.rand() < 0.5:
+                a.remove_leaf(iid, path)
+            else:
+                a.remove_tail(iid, path, m)
+            for j in range(m):
+                _remove_leaf_ref(b, iid, path[:len(path) - j])
+        elif op < 0.97:
+            for ix in (a, b):
+                ix.remove_instance(iid)
+        else:
+            seed = rng.randint(1 << 30)
+            assert a.corrupt_bit(seed) == b.corrupt_bit(seed)
+        assert a._top == b._top
+        top = a._top
+        assert np.array_equal(a._masks[:top], b._masks[:top]), step
+        assert a._pop[:top] == b._pop[:top], step
+        assert a._free == b._free, step
+        assert (a._dig, a._bits) == (b._dig, b._bits), step
 
 
 def test_walk_reuse_lcp_edge_cases():
@@ -225,6 +329,7 @@ def test_property_flat_index_matches_reference():
                 pair.kvs[iid].evict_tokens(arg * B)
             else:
                 pair.kvs[iid].clear()
+            pair.check_state()
         pair.check(list(probes) + [()])
         pair.rebuild_matches(list(probes))
 

@@ -110,3 +110,108 @@ def test_property_capacity_never_exceeded_after_insert(seqs, cap_blocks):
     for s in seqs:
         kv.insert(tuple(s))
         assert kv.tokens_stored <= cap_blocks * B
+
+
+def _scan_leaves(kv):
+    """Every non-root node without children, by a full tree walk."""
+    out, stack = set(), [kv.root]
+    while stack:
+        n = stack.pop()
+        if n is not kv.root and not n.children:
+            out.add(n)
+        stack.extend(n.children.values())
+    return out
+
+
+class _ScanLRU(RadixKVIndex):
+    """The leaf-scanning LRU the kept leaf set replaced: every eviction
+    walks the whole tree for its leaves and reports each evicted leaf
+    on its own, root→leaf path rebuilt per leaf."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.evicted = []
+
+    def _evict_to_capacity(self):
+        import heapq
+        leaves = [(n.last_use, id(n), n) for n in _scan_leaves(self)]
+        heapq.heapify(leaves)
+        while self.tokens_stored > self.capacity_tokens and leaves:
+            _, _, leaf = heapq.heappop(leaves)
+            if leaf.children or leaf.parent is None:
+                continue  # stale entry
+            parent = leaf.parent
+            path, n = [], leaf
+            while n.parent is not None:
+                path.append(n.key)
+                n = n.parent
+            path.reverse()
+            self.evicted.append(path)
+            del parent.children[leaf.key]
+            leaf.parent = None
+            self._n_blocks -= 1
+            if parent is not self.root and not parent.children:
+                heapq.heappush(leaves,
+                               (parent.last_use, id(parent), parent))
+
+
+def _recording(kv):
+    """Attach an ``on_evict`` that expands each run back into leaves."""
+    runs, leaves = [], []
+
+    def on_evict(path, m):
+        runs.append((list(path), m))
+        leaves.extend(list(path[:len(path) - j]) for j in range(m))
+    kv.on_evict = on_evict
+    return runs, leaves
+
+
+def test_eviction_reports_one_run_per_chain_tail():
+    kv = RadixKVIndex(block_size=B, capacity_tokens=B)
+    runs, leaves = _recording(kv)
+    kv.insert((1, 2, 3))       # 3 then 2 leave: one run of 2
+    assert runs == [([1, 2, 3], 2)]
+    assert leaves == [[1, 2, 3], [1, 2]]
+    assert list(kv.chains()) == [[1]]
+    assert _scan_leaves(kv) == kv._leaves
+    kv.insert((4, 5))          # LRU is 1 (its own run), then 5
+    assert runs[1:] == [([1], 1), ([4, 5], 1)]
+    assert list(kv.chains()) == [[4]]
+
+
+@settings(max_examples=200, deadline=None)
+@given(st.integers(1, 6),
+       st.lists(st.tuples(st.sampled_from(
+                    ["insert", "insert", "match", "peek", "evict",
+                     "clear"]),
+                          st.lists(st.integers(0, 3), min_size=1,
+                                   max_size=7),
+                          st.integers(1, 4)),
+                min_size=1, max_size=50))
+def test_property_run_eviction_matches_leaf_scan(cap_blocks, ops):
+    """The kept-leaf, run-reporting LRU evicts the same leaves in the
+    same order as the leaf-scanning LRU, leaves the same tree, and its
+    leaf set equals a full scan's after every step."""
+    kv = RadixKVIndex(block_size=B, capacity_tokens=cap_blocks * B)
+    ref = _ScanLRU(block_size=B, capacity_tokens=cap_blocks * B)
+    runs, leaves = _recording(kv)
+    for kind, seq, k in ops:
+        seq = tuple(seq)
+        for t in (kv, ref):
+            if kind == "insert":
+                t.insert(seq)
+            elif kind == "match":
+                t.match(seq, touch=True)
+            elif kind == "peek":
+                t.match(seq, touch=False)
+            elif kind == "evict":
+                t.evict_tokens(k * B)
+            else:
+                t.clear()
+        assert leaves == ref.evicted
+        assert sorted(kv.chains()) == sorted(ref.chains())
+        assert kv.tokens_stored == ref.tokens_stored
+        assert kv._leaves == _scan_leaves(kv)
+        # a run is a chain tail taken bottom-up: never longer than its
+        # deepest leaf's path
+        assert all(1 <= m <= len(path) for path, m in runs)

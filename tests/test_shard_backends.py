@@ -66,9 +66,12 @@ def _apply_ops(rng, ref, idxs, n, steps):
             held.append((iid, chain))
         elif op < 0.70:
             iid, chain = held.pop(int(rng.integers(0, len(held))))
-            ref.remove_leaf(iid, chain)
-            for ix in idxs.values():
-                ix.remove_leaf(iid, chain)
+            # m == 0: one leaf; else one eviction run of the deepest m
+            m = int(rng.integers(0, len(chain) + 1))
+            args = (iid, chain, m) if m else (iid, chain)
+            name = "remove_tail" if m else "remove_leaf"
+            for ix in (ref, *idxs.values()):
+                getattr(ix, name)(*args)
         elif op < 0.78:
             iid = int(rng.integers(0, n))
             ref.remove_instance(iid)
@@ -217,7 +220,12 @@ if HAVE_HYPOTHESIS:
     @st.composite
     def interleavings(draw):
         ops = []
-        held = []
+        # per instance, the prefix-closed set of chains it holds: a
+        # removal takes only what the radix callback protocol can (a
+        # leaf, or a run of leaves up a chain tail), since a removal
+        # that breaks prefix-closure leaves hit vectors undefined
+        # (the subset invariant)
+        held = {}
         for _ in range(draw(st.integers(10, 60))):
             kind = draw(st.sampled_from(
                 ["add", "add", "add", "remove", "drop", "walk"]))
@@ -225,15 +233,32 @@ if HAVE_HYPOTHESIS:
                 iid = draw(st.integers(0, 31))
                 chain = tuple(draw(st.lists(st.integers(0, 5),
                                             min_size=1, max_size=8)))
-                held.append((iid, chain))
+                held.setdefault(iid, set()).update(
+                    chain[:d] for d in range(1, len(chain) + 1))
                 ops.append(("add", iid, chain))
             elif kind == "remove" and held:
-                i = draw(st.integers(0, len(held) - 1))
-                iid, chain = held.pop(i)
-                ops.append(("remove_leaf", iid, chain))
+                iid = draw(st.sampled_from(sorted(held)))
+                nodes = held[iid]
+                inner = {p[:-1] for p in nodes}
+                leaf = draw(st.sampled_from(sorted(nodes - inner)))
+                # the longest run: climb while the parent has no other
+                # child left, so it becomes the next leaf
+                top = 1
+                while (top < len(leaf) and sum(
+                        1 for p in nodes
+                        if p[:-1] == leaf[:len(leaf) - top]) == 1):
+                    top += 1
+                # m == 0: one leaf; else one eviction run of m leaves
+                m = draw(st.integers(0, top))
+                nodes.difference_update(
+                    leaf[:len(leaf) - j] for j in range(max(m, 1)))
+                if not nodes:
+                    del held[iid]
+                ops.append(("remove_tail", iid, leaf, m) if m
+                           else ("remove_leaf", iid, leaf))
             elif kind == "drop":
                 iid = draw(st.integers(0, 31))
-                held = [(i, c) for i, c in held if i != iid]
+                held.pop(iid, None)
                 ops.append(("remove_instance", iid))
             else:
                 qs = draw(st.lists(

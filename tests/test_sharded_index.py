@@ -49,8 +49,10 @@ class _Trio:
                               capacity_tokens=capacity_tokens)
             kv.on_insert = (lambda blocks, _i=i: [
                 idx.add(_i, blocks) for idx in self.all])
-            kv.on_evict = (lambda path, _i=i: [
-                idx.remove_leaf(_i, path) for idx in self.all])
+            kv.on_evict = (lambda path, m, _i=i: [
+                idx.remove_tail(_i, path, m) for idx in self.all[:2]]
+                + [self.ref.remove_leaf(_i, path[:len(path) - j])
+                   for j in range(m)])
             kv.on_clear = (lambda _i=i: [
                 idx.remove_instance(_i) for idx in self.all])
             self.kvs.append(kv)
