@@ -1,4 +1,9 @@
-"""Qwen3-30B-A3B MoE — the paper's MoE testbed model (§4.1)."""
+"""Qwen3-30B-A3B MoE — the paper's MoE testbed model (§4.1).
+
+Every layer is sparse (``decoder_sparse_step`` 1, no ``mlp_only_layers``):
+128 experts of width 768, 8 per token, no shared expert, so the dense
+``intermediate_size`` (6,144) of the published config is never used.
+"""
 from repro.models.config import ModelConfig, dense_pattern
 
 
@@ -19,5 +24,5 @@ def config() -> ModelConfig:
         n_experts=128,
         top_k=8,
         moe_d_ff=768,
-        source="paper §4.1 testbed (Qwen3-30B-A3B)",
+        source="https://huggingface.co/Qwen/Qwen3-30B-A3B/blob/main/config.json",
     )

@@ -47,6 +47,10 @@ class ModelConfig:
     n_experts: int = 0                  # 0 -> dense FFN
     top_k: int = 0
     moe_d_ff: int = 0                   # 0 -> d_ff
+    # expert parallelism: this device holds experts
+    # [expert_first, expert_first + experts_held) of every MoE layer
+    expert_first: int = 0
+    experts_held: int = 0               # 0 -> all n_experts
     dense_residual_d_ff: int = 0        # arctic: parallel dense FFN next to MoE
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01       # load-balance auxiliary loss weight
@@ -85,6 +89,10 @@ class ModelConfig:
             f"n_layers {self.n_layers}")
         for k in self.block_pattern:
             assert k in BLOCK_KINDS, k
+        last = self.expert_first + self.resolved_experts_held
+        if self.expert_first < 0 or last > self.n_experts:
+            raise ValueError(f"{self.name}: experts held [{self.expert_first}"
+                             f", {last}) not in [0, {self.n_experts})")
 
     # ------------------------------------------------------------------
     @property
@@ -106,6 +114,10 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def is_encdec(self) -> bool:
@@ -140,7 +152,7 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def param_count(self) -> int:
-        """Analytic parameter count (total, incl. all experts)."""
+        """Analytic parameter count (total, incl. every expert held)."""
         D, H, KV, hd = self.d_model, self.n_heads, self.n_kv_heads, self.resolved_head_dim
         F, V = self.d_ff, self.vocab_size
         total = V * D                              # embed
@@ -154,7 +166,8 @@ class ModelConfig:
                     attn *= 2                      # self + cross
                 total += attn
                 if self.n_experts:
-                    total += self.n_experts * n_ffn_mats * D * self.resolved_moe_d_ff
+                    total += (self.resolved_experts_held * n_ffn_mats * D
+                              * self.resolved_moe_d_ff)
                     total += D * self.n_experts    # router
                     if self.dense_residual_d_ff:
                         total += n_ffn_mats * D * self.dense_residual_d_ff
@@ -182,8 +195,8 @@ class ModelConfig:
             return self.param_count()
         n_ffn_mats = 3 if self.ffn_act in ("swiglu", "geglu") else 2
         moe_layers = sum(1 for k in self.block_pattern if k in ("attn", "swa"))
-        inactive = (self.n_experts - self.top_k) * n_ffn_mats * \
-            self.d_model * self.resolved_moe_d_ff * moe_layers
+        inactive = max(0, self.resolved_experts_held - self.top_k) * \
+            n_ffn_mats * self.d_model * self.resolved_moe_d_ff * moe_layers
         return self.param_count() - inactive
 
     # ------------------------------------------------------------------
@@ -211,6 +224,8 @@ class ModelConfig:
             dense_residual_d_ff=min(self.dense_residual_d_ff, 256),
             vocab_size=min(self.vocab_size, 512),
             n_experts=min(self.n_experts, 4),
+            expert_first=0,
+            experts_held=0,
             top_k=min(self.top_k, 2),
             d_rnn=min(self.resolved_d_rnn, 256) if self.d_rnn or True else 0,
             window_size=min(self.window_size, 64),

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
+from ..kernels.moe_experts import held_experts_op
 
 
 # ---------------------------------------------------------------------------
@@ -101,18 +102,27 @@ def ffn_apply(p, x, act="swiglu"):
 
 
 # ---------------------------------------------------------------------------
-# MoE: top-k routing with per-group capacity dispatch (sort-free one-hot
-# cumsum — GShard-style but with the (tokens, E) cumsum done per group so
-# the dispatch bookkeeping stays tiny; expert compute is an
-# einsum over (E, capacity, ·) buffers that shards cleanly: experts over
-# the "model" axis when divisible, else the FFN dim).
+# MoE.  Two layers share one parameter layout: the router over all
+# ``n_experts`` and the weights of the experts held here
+# (``cfg.expert_first`` on, ``cfg.resolved_experts_held`` of them).
+#
+# * ``moe_apply`` (training): top-k routing with per-group capacity
+#   dispatch (sort-free one-hot cumsum — GShard-style but with the
+#   (tokens, E) cumsum done per group so the dispatch bookkeeping stays
+#   tiny; expert compute is an einsum over (E, capacity, ·) buffers that
+#   shards cleanly: experts over the "model" axis when divisible, else the
+#   FFN dim).  Holds every expert; drops choices past capacity.
+# * ``moe_dropless`` (serving): every choice that lands on a held expert
+#   goes through it, in one kernel over the held experts some token
+#   picked (``kernels/moe_experts.py``).
 # ---------------------------------------------------------------------------
 
 def init_moe(key, cfg: ModelConfig, dtype):
-    D, E, F = cfg.d_model, cfg.n_experts, cfg.resolved_moe_d_ff
+    D, F = cfg.d_model, cfg.resolved_moe_d_ff
+    E = cfg.resolved_experts_held
     ks = jax.random.split(key, 5)
     p = {
-        "router": init_linear(ks[0], D, E, jnp.float32),
+        "router": init_linear(ks[0], D, cfg.n_experts, jnp.float32),
         "wi": {"w": _dense_init(ks[1], (E, D, F), dtype)},
         "wg": {"w": _dense_init(ks[2], (E, D, F), dtype)},
         "wo": {"w": _dense_init(ks[3], (E, F, D), dtype)},
@@ -131,7 +141,7 @@ def moe_apply(p, x, cfg: ModelConfig):
     """
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
-    F = cfg.resolved_moe_d_ff
+    assert cfg.resolved_experts_held == E, "capacity dispatch holds all"
     cap = int(math.ceil(S * K / E * cfg.capacity_factor))
     cap = max(cap, K)
 
@@ -189,6 +199,52 @@ def moe_apply(p, x, cfg: ModelConfig):
     if "dense" in p:                                           # arctic residual
         y = y + ffn_apply(p["dense"], x, cfg.ffn_act)
     return y, aux
+
+
+def _route(p, x, cfg: ModelConfig):
+    """Router logits over all ``n_experts`` in float32, softmax, top-k,
+    renormalised over the k (Qwen3-MoE's ``norm_topk_prob``): weights
+    and expert ids, each (T, K)."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"]["w"],
+                     precision=jax.lax.Precision.HIGHEST)
+    topw, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    return topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9), topi
+
+
+def moe_dropless(p, x, cfg: ModelConfig):
+    """x: (..., D) -> (y (..., D), counts (held,) int32).
+
+    Every routing choice that lands on a held expert goes through that
+    expert, whatever the imbalance; a choice that lands elsewhere adds
+    nothing here (another device's share).  The held experts that some
+    token picked run as one grouped kernel
+    (``kernels.moe_experts.held_experts_op``), which reads no other
+    expert's weights.  ``counts[e]``: the choices held expert ``e`` took.
+
+    ``p["layer"]``, where present, says the expert weights are stacked
+    over a scan's layers, (layers, held, ·, ·), and this call is layer
+    ``p["layer"]``: the kernel then reads that layer's experts in place
+    in the stack, where a slice taken per scan step would be copied whole
+    before it could be read.
+    """
+    shape, D = x.shape, x.shape[-1]
+    xt = x.reshape(-1, D)
+    held = cfg.resolved_experts_held
+    topw, topi = _route(p, xt, cfg)
+    pick = (topi - cfg.expert_first)[..., None] == jnp.arange(held)
+    comb = jnp.sum(jnp.where(pick, topw[..., None], 0.0), axis=1)
+    counts = jnp.sum(pick, axis=(0, 1), dtype=jnp.int32)
+    w = [p[k]["w"] for k in ("wi", "wg", "wo")]
+    base = 0
+    if "layer" in p:                           # every layer's experts
+        base = p["layer"] * held
+        w = [v.reshape(-1, *v.shape[2:]) for v in w]
+    y = held_experts_op(xt, comb, counts, *w, jnp.asarray(base, jnp.int32),
+                        act=cfg.ffn_act)
+    y = y.astype(x.dtype).reshape(shape)
+    if "dense" in p:                                           # arctic residual
+        y = y + ffn_apply(p["dense"], x, cfg.ffn_act)
+    return y, counts
 
 
 def init_embed(key, vocab, d_model, dtype):

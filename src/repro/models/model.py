@@ -6,7 +6,24 @@ any remainder layers unrolled.  Three entry points:
 
 * ``forward_train``  — full forward + CE loss (+ MoE aux, z-loss)
 * ``prefill``        — forward returning logits + populated cache
+* ``prefill_cached`` — a prompt chunk continuing a cache (engine prefill)
 * ``decode_step``    — one token with cache (the serving hot path)
+
+MoE layers take the capacity dispatch (``layers.moe_apply``) in
+``forward_train`` and ``prefill`` (the dry-run's shapes) and the dropless
+layer over the held experts (``layers.moe_dropless``) in the engine's two
+programs, ``prefill_cached`` and ``decode_step``.  The one-shot
+``prefill`` keeps the capacity dispatch for the dry-run: it lowers
+``prefill`` on meshes that shard the experts over the "model" axis, where
+the capacity einsums partition and the dropless layer's kernel would not
+(GSPMD would gather every expert's weights to each device).  The engine's
+two programs return, in the cache, what each MoE layer did in that call:
+under ``"moe_counts"`` the routing choices it gave each held expert,
+(MoE layers, experts held) int32, and under ``"moe_tap"`` the expert
+layer's input and output (``"h"``, ``"y"``, each (MoE layers, rows, d))
+at as many of the call's token rows as the incoming tap has: a decode
+step's every slot, a prefill chunk's rows spread from its first token to
+its last (``tap_rows``).  A check compares them with a reference.
 
 Caches are pytrees mirroring the unit structure; attention blocks hold
 (k, v) ring/linear buffers, recurrent blocks hold fixed-size state.
@@ -19,12 +36,14 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ModelConfig, KV_BLOCKS
 from . import attention as attn
 from . import recurrent as rec
 from .layers import (embed_apply, ffn_apply, init_embed, init_ffn, init_moe,
-                     init_norm, linear, moe_apply, norm_apply, init_linear)
+                     init_norm, linear, moe_apply, moe_dropless, norm_apply,
+                     init_linear)
 
 VISION_DIM = 1152  # stub SigLIP patch-embedding width (paligemma)
 
@@ -66,12 +85,43 @@ def _init_block(key, cfg: ModelConfig, kind: str):
     return p
 
 
-def _ffn_or_moe(p, x, cfg):
-    if cfg.n_experts and "router" in p:
-        return moe_apply(p, x, cfg)
+def _dense_ffn(p, x, cfg):
     act = "geglu" if "wg" in p and cfg.ffn_act == "geglu" else (
         "swiglu" if "wg" in p else "gelu")
-    return ffn_apply(p, x, act), 0.0
+    return ffn_apply(p, x, act)
+
+
+def _ffn_or_moe(p, x, cfg):
+    """Training-path FFN: (y, MoE aux loss)."""
+    if cfg.n_experts and "router" in p:
+        return moe_apply(p, x, cfg)
+    return _dense_ffn(p, x, cfg), 0.0
+
+
+def tap_rows(n: int, rows: int):
+    """The ``rows`` of a call's ``n`` token rows whose expert layer the
+    call taps: all of them where ``n == rows``, else spread evenly from
+    the first to the last (repeated where ``n < rows``)."""
+    if n == rows:
+        return None
+    if rows == 1:
+        return np.array([n - 1])
+    return np.arange(rows) * (n - 1) // (rows - 1)
+
+
+def _ffn_serve(p, x, cfg, rows=None):
+    """Serving-path FFN: (y, None if dense, else (per-held-expert counts,
+    the expert layer's input and output at ``rows`` of the call's token
+    rows, all of them by default))."""
+    if not (cfg.n_experts and "router" in p):
+        return _dense_ffn(p, x, cfg), None
+    y, counts = moe_dropless(p, x, cfg)
+    D = x.shape[-1]
+    xt, yt = x.reshape(-1, D), y.reshape(-1, D)
+    idx = tap_rows(xt.shape[0], rows or xt.shape[0])
+    if idx is not None:
+        xt, yt = xt[idx], yt[idx]
+    return y, (counts, xt, yt)
 
 
 def _block_prefill(p, kind, x, positions, cfg, enc_out, state_in):
@@ -111,36 +161,37 @@ def _block_prefill(p, kind, x, positions, cfg, enc_out, state_in):
 
 
 def _block_decode(p, kind, x, pos, cfg, cache):
-    """Returns (x, new_cache_entry)."""
+    """Returns (x, new_cache_entry, MoE outputs (``_ffn_serve``) or
+    None)."""
     if kind in ("attn", "swa", "xattn"):
         h, cache = attn.attn_decode(p["attn"],
                                     norm_apply(p["norm1"], x, cfg.norm_eps),
                                     cache, pos, cfg, kind)
         x = x + h
-        h, _ = _ffn_or_moe(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
-                           cfg)
-        return x + h, cache
+        h, n = _ffn_serve(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
+                          cfg)
+        return x + h, cache, n
     if kind == "rglru":
         h, st = rec.rglru_decode(p["rglru"],
                                  norm_apply(p["norm1"], x, cfg.norm_eps),
                                  cache, cfg)
         x = x + h
-        h, _ = _ffn_or_moe(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
-                           cfg)
-        return x + h, st
+        h, n = _ffn_serve(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
+                          cfg)
+        return x + h, st, n
     if kind == "mlstm":
         h, st = rec.mlstm_decode(p["mlstm"],
                                  norm_apply(p["norm1"], x, cfg.norm_eps),
                                  cache, cfg)
-        return x + h, st
+        return x + h, st, None
     if kind == "slstm":
         h, st = rec.slstm_decode(p["slstm"],
                                  norm_apply(p["norm1"], x, cfg.norm_eps),
                                  cache, cfg)
         x = x + h
-        h, _ = _ffn_or_moe(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
-                           cfg)
-        return x + h, st
+        h, n = _ffn_serve(p["ffn"], norm_apply(p["norm2"], x, cfg.norm_eps),
+                          cfg)
+        return x + h, st, n
     raise ValueError(kind)
 
 
@@ -227,6 +278,10 @@ class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.unit, self.n_units, self.remainder = cfg.repeating_unit()
+        #: layers with a router (attention blocks of an MoE model)
+        self.moe_layers = (sum(k in ("attn", "swa", "xattn")
+                               for k in cfg.block_pattern)
+                           if cfg.n_experts else 0)
 
     # -------------------------------------------------------------- init
     def init(self, rng) -> Dict[str, Any]:
@@ -406,11 +461,62 @@ class Model:
                     a, (self.n_units,) + a.shape).copy(), c))
         rest = tuple(_init_block_cache(k, B, cache_len, cfg)
                      for k in self.remainder)
-        return {"units": tuple(unit_caches), "rest": rest,
-                "enc_out": (jnp.zeros((B, cfg.enc_seq,
-                                       cfg.enc_d_model or cfg.d_model),
-                                      jnp.dtype(cfg.dtype))
-                            if cfg.is_encdec else ())}
+        cache = {"units": tuple(unit_caches), "rest": rest,
+                 "enc_out": (jnp.zeros((B, cfg.enc_seq,
+                                        cfg.enc_d_model or cfg.d_model),
+                                       jnp.dtype(cfg.dtype))
+                             if cfg.is_encdec else ())}
+        if self.moe_layers:
+            cache["moe_counts"] = jnp.zeros(
+                (self.moe_layers, cfg.resolved_experts_held), jnp.int32)
+            tap = jnp.zeros((self.moe_layers, B, cfg.d_model),
+                            jnp.dtype(cfg.dtype))
+            cache["moe_tap"] = {"h": tap, "y": tap}
+        return cache
+
+    def _scan_inputs(self, params, cache):
+        """The serving scans' inputs, and the stacked expert weights of
+        each unit position (``None`` where it has none).  A dense model's
+        are as they always were.  An MoE model's expert weights leave the
+        scanned inputs and the scan counts layers: ``moe_dropless`` reads
+        a layer's experts in place in the stack (``"layer"``)."""
+        xs = {"params": params["units"], "cache": cache["units"]}
+        if not self.moe_layers:
+            return xs, None
+        units, stacks = [], []
+        for p in params["units"]:
+            f = p.get("ffn", {})
+            w = ({k: f[k] for k in ("wi", "wg", "wo") if k in f}
+                 if "router" in f else None)
+            units.append(p if w is None else dict(p, ffn={
+                k: v for k, v in f.items() if k not in w}))
+            stacks.append(w)
+        xs.update(params=tuple(units), layer=jnp.arange(self.n_units))
+        return xs, stacks
+
+    @staticmethod
+    def _unit_params(xs, stacks):
+        """One scan step's unit parameters, with the stacked experts."""
+        if stacks is None:
+            return xs["params"]
+        return tuple(p if w is None else
+                     dict(p, ffn=dict(p["ffn"], layer=xs["layer"], **w))
+                     for p, w in zip(xs["params"], stacks))
+
+    def _with_moe(self, cache, unit_moe, rest_moe):
+        """``cache`` with the call's MoE outputs (``_ffn_serve``) in layer
+        order: ``"moe_counts"`` (MoE layers, held) and ``"moe_tap"``;
+        a dense model's cache is returned as it is."""
+        parts = [m for m in unit_moe if m is not None]
+        out = ([jax.tree.map(lambda *a: jnp.stack(a, axis=1).reshape(
+            -1, *a[0].shape[1:]), *parts)] if parts else [])
+        out += [jax.tree.map(lambda a: a[None], m) for m in rest_moe
+                if m is not None]
+        if out:
+            counts, h, y = jax.tree.map(lambda *a: jnp.concatenate(a), *out)
+            cache["moe_counts"] = counts
+            cache["moe_tap"] = {"h": h, "y": y}
+        return cache
 
     def prefill(self, params, tokens, batch=None, positions=None,
                 last_only=False):
@@ -455,38 +561,45 @@ class Model:
                              jnp.clip(positions, 0, cfg.max_position - 1),
                              axis=0)
 
+        rows = (cache["moe_tap"]["h"].shape[1] if "moe_tap" in cache
+                else 1)
+
         def block_step(p, kind, x, c):
             if kind in ("attn", "swa", "xattn"):
                 h, c2 = attn.attn_prefill_cached(
                     p["attn"], norm_apply(p["norm1"], x, cfg.norm_eps),
                     positions, cfg, kind, c, cache_len, enc_out)
                 x = x + h
-                h, _ = _ffn_or_moe(p["ffn"],
-                                   norm_apply(p["norm2"], x, cfg.norm_eps),
-                                   cfg)
-                return x + h, c2
+                h, n = _ffn_serve(p["ffn"],
+                                  norm_apply(p["norm2"], x, cfg.norm_eps),
+                                  cfg, rows)
+                return x + h, c2, n
             # recurrent blocks: plain prefill continuation from state
             x2, c2, _ = _block_prefill(p, kind, x, positions, cfg, enc_out, c)
-            return x2, c2
+            return x2, c2, None
+
+        scan_xs, stacks = self._scan_inputs(params, cache)
 
         def unit_body(x, xs):
-            p_j, c_j = xs["params"], xs["cache"]
-            new_c = []
+            p_j, c_j = self._unit_params(xs, stacks), xs["cache"]
+            new_c, moe = [], []
             for j, kind in enumerate(self.unit):
-                x, c2 = block_step(p_j[j], kind, x, c_j[j])
+                x, c2, n = block_step(p_j[j], kind, x, c_j[j])
                 new_c.append(c2)
-            return x, tuple(new_c)
+                moe.append(n)
+            return x, (tuple(new_c), tuple(moe))
 
-        x, new_unit = jax.lax.scan(
-            unit_body, x, {"params": params["units"],
-                           "cache": cache["units"]})
-        new_rest = []
+        x, (new_unit, unit_moe) = jax.lax.scan(unit_body, x, scan_xs)
+        new_rest, rest_moe = [], []
         for j, kind in enumerate(self.remainder):
-            x, c2 = block_step(params["rest"][j], kind, x, cache["rest"][j])
+            x, c2, n = block_step(params["rest"][j], kind, x,
+                                  cache["rest"][j])
             new_rest.append(c2)
+            rest_moe.append(n)
         logits = self._logits(params, x)[..., :cfg.vocab_size]
-        return logits, {"units": new_unit, "rest": tuple(new_rest),
-                        "enc_out": cache.get("enc_out", ())}
+        return logits, self._with_moe(
+            {"units": new_unit, "rest": tuple(new_rest),
+             "enc_out": cache.get("enc_out", ())}, unit_moe, rest_moe)
 
     def decode_step(self, params, token, pos, cache):
         """token: (B,1) int32; pos: (B,) absolute position. Returns
@@ -496,22 +609,25 @@ class Model:
         positions = pos[:, None]
         x, positions = self._embed_inputs(params, token, {}, positions)
 
-        def unit_body(x, xs):
-            p_j, c_j = xs["params"], xs["cache"]
-            new_c = []
-            for j, kind in enumerate(self.unit):
-                x, c = _block_decode(p_j[j], kind, x, pos, cfg, c_j[j])
-                new_c.append(c)
-            return x, tuple(new_c)
+        scan_xs, stacks = self._scan_inputs(params, cache)
 
-        x, new_unit = jax.lax.scan(
-            unit_body, x, {"params": params["units"],
-                           "cache": cache["units"]})
-        new_rest = []
+        def unit_body(x, xs):
+            p_j, c_j = self._unit_params(xs, stacks), xs["cache"]
+            new_c, moe = [], []
+            for j, kind in enumerate(self.unit):
+                x, c, n = _block_decode(p_j[j], kind, x, pos, cfg, c_j[j])
+                new_c.append(c)
+                moe.append(n)
+            return x, (tuple(new_c), tuple(moe))
+
+        x, (new_unit, unit_moe) = jax.lax.scan(unit_body, x, scan_xs)
+        new_rest, rest_moe = [], []
         for j, kind in enumerate(self.remainder):
-            x, c = _block_decode(params["rest"][j], kind, x, pos, cfg,
-                                 cache["rest"][j])
+            x, c, n = _block_decode(params["rest"][j], kind, x, pos, cfg,
+                                    cache["rest"][j])
             new_rest.append(c)
+            rest_moe.append(n)
         logits = self._logits(params, x)[..., :cfg.vocab_size]
-        return logits, {"units": new_unit, "rest": tuple(new_rest),
-                        "enc_out": cache.get("enc_out", ())}
+        return logits, self._with_moe(
+            {"units": new_unit, "rest": tuple(new_rest),
+             "enc_out": cache.get("enc_out", ())}, unit_moe, rest_moe)

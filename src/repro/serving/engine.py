@@ -25,8 +25,17 @@ the tokens on the host; bs), and the counters
 ``engine.fragment_d2h_bytes``/``engine.fragment_h2d_bytes`` (fragment
 leaves' bytes) and ``engine.predicted_hit_tokens``/
 ``engine.reused_tokens`` (the router's hit for an admitted request and
-the tokens the engine took from its store).  With ``obs=None`` none of
-it runs (Contract 5).
+the tokens the engine took from its store).  An MoE model's steps also
+count, from the ``moe_counts`` its programs return in the cache (a
+decode step computes every slot, a free one's stale token too):
+``engine.moe_routed_local`` (routing choices that landed on a held
+expert, prefill and decode), and over decode steps and MoE layers
+``engine.moe_layer_steps``, ``engine.moe_experts_touched`` (held experts
+given at least one choice) and ``engine.moe_max_expert_tokens`` (the
+most choices one held expert took).  With ``obs=None`` none of it runs
+and nothing is copied to the host (Contract 5).  The ``moe_tap`` that an
+MoE model's programs also leave in the cache (``Model``) stays on the
+device: the engine reads nothing of it.
 
 Encoder-decoder archs (whisper) are not served by this engine (the
 cluster simulator covers their scheduling); everything decoder-only —
@@ -68,13 +77,20 @@ def _slice_slot(cache, b: int):
 
 
 def _write_slot(cache, sub, b: int):
+    """Write a B=1 view into slot b; the MoE counts and tap of a
+    program's call (``sub`` from ``prefill_cached``) replace the
+    cache's."""
     units = jax.tree.map(
         lambda l, s: jax.lax.dynamic_update_slice_in_dim(l, s, b, axis=1),
         cache["units"], sub["units"])
     rest = jax.tree.map(
         lambda l, s: jax.lax.dynamic_update_slice_in_dim(l, s, b, axis=0),
         cache["rest"], sub["rest"])
-    return {"units": units, "rest": rest, "enc_out": cache.get("enc_out", ())}
+    out = {"units": units, "rest": rest, "enc_out": cache.get("enc_out", ())}
+    for k in ("moe_counts", "moe_tap"):
+        if k in cache:
+            out[k] = sub.get(k, cache[k])
+    return out
 
 
 def _zero_slot(cache, b: int):
@@ -160,6 +176,8 @@ def prefill_slot(model: Model, params, cache, tokens, positions, cache_len,
     """Prefill one chunk into slot ``b``; returns (last-token logits,
     cache)."""
     sub = _slice_slot(cache, b)
+    if "moe_tap" in cache:                  # its shape sets the rows tapped
+        sub["moe_tap"] = cache["moe_tap"]
     logits, new_sub = model.prefill_cached(
         params, tokens, positions, sub, cache_len[None])
     return logits[:, -1], _write_slot(cache, new_sub, b)
@@ -171,6 +189,14 @@ def decode_step(model: Model, params, cache, tokens, pos):
     cache)."""
     logits, cache = model.decode_step(params, tokens, pos, cache)
     return jnp.argmax(logits[:, -1], axis=-1), cache
+
+
+def _count_decode(reg, counts: np.ndarray):
+    """A decode step's MoE counters from its (MoE layers, held) counts."""
+    reg.inc("engine.moe_routed_local", int(counts.sum()))
+    reg.inc("engine.moe_layer_steps", counts.shape[0])
+    reg.inc("engine.moe_experts_touched", int((counts > 0).sum()))
+    reg.inc("engine.moe_max_expert_tokens", int(counts.max(axis=1).sum()))
 
 
 class InstanceEngine:
@@ -195,7 +221,8 @@ class InstanceEngine:
         if device is not None:
             self.cache = jax.device_put(self.cache, device)
         #: bytes of one slot of the cache: a stored fragment's size
-        self.slot_bytes = _nbytes(self.cache) // max_batch
+        self.slot_bytes = _nbytes((self.cache["units"],
+                                   self.cache["rest"])) // max_batch
         self.obs = obs
         self.prefix_store = PrefixStore(
             prefix_capacity,
@@ -288,6 +315,9 @@ class InstanceEngine:
                     self.model, self.params, self.cache, toks, positions,
                     cache_len, seq.slot)
                 logits.block_until_ready()
+                if reg is not None and "moe_counts" in self.cache:
+                    reg.inc("engine.moe_routed_local",
+                            int(np.asarray(self.cache["moe_counts"]).sum()))
             events["prefill_tokens"] = n
             seq.pos += n
             if seq.pos >= min(len(seq.tokens), self.max_len):
@@ -327,6 +357,8 @@ class InstanceEngine:
                 nxt, self.cache = decode_step(self.model, self.params,
                                               self.cache, toks, pos)
                 nxt = np.asarray(nxt)
+                if reg is not None and "moe_counts" in self.cache:
+                    _count_decode(reg, np.asarray(self.cache["moe_counts"]))
             for slot, seq in list(self.running.items()):
                 tok = int(nxt[slot])
                 seq.out_tokens.append(tok)

@@ -2,8 +2,10 @@
 
 The TPU compiler is installed beside JAX, and it compiles for a chip
 that is described rather than present.  These tests compile the served
-path's device programs at their real sizes: the router's wave loop and
-a full-width qwen3_4b engine's jitted ``decode`` and ``prefill_slot``.
+path's device programs at their real sizes: the router's wave loop, a
+full-width qwen3_4b engine's jitted ``decode`` and ``prefill_slot``, and
+those of a Qwen3-30B-A3B engine holding one chip's share of the experts
+(16 of 128 per layer, 8 slots of 1,152 positions).
 A compile that passes says nothing about results or times; it says the
 chip's compiler takes the program and that the program fits the chip's
 16 GiB of HBM.
@@ -92,3 +94,39 @@ def test_engine_prefill_slot_compiles_for_v5e(one_chip, qwen3_4b):
     compiled = prefill_slot.lower(model, params, cache, i32(1, CHUNK),
                                   i32(1, CHUNK), i32(), i32()).compile()
     _assert_fits(compiled)
+
+
+A3B_BATCH, A3B_LEN, A3B_PROMPT = 8, 1152, 1152
+
+
+@pytest.fixture(scope="module")
+def a3b(one_chip):
+    """Qwen3-30B-A3B at full width and depth, experts 0-15 held."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import Model
+    model = Model(dataclasses.replace(get_config("qwen3_30b_moe"),
+                                      experts_held=16))
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    cache = jax.eval_shape(lambda: model.init_cache(A3B_BATCH, A3B_LEN))
+    return model, _on(one_chip, params), _on(one_chip, cache)
+
+
+def test_a3b_engine_programs_compile_for_v5e(one_chip, a3b):
+    """Both programs compile with the dropless expert layer, its grouped
+    kernel lowered by Mosaic (one TPU custom call in each program's layer
+    scan, not the interpret-mode fallback), and a whole-prompt prefill
+    fits beside the weights (10.36e9 B)."""
+    from repro.serving.engine import decode_step, prefill_slot
+    model, params, cache = a3b
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = decode_step.lower(model, params, cache, i32(A3B_BATCH, 1),
+                                 i32(A3B_BATCH)).compile()
+    _assert_fits(compiled)
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    compiled = prefill_slot.lower(model, params, cache, i32(1, A3B_PROMPT),
+                                  i32(1, A3B_PROMPT), i32(), i32()).compile()
+    _assert_fits(compiled)
+    assert compiled.as_text().count("tpu_custom_call") == 1
