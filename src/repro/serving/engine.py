@@ -9,6 +9,17 @@ fragments (or recurrent-state snapshots) keyed by block-hash chains: on a
 KV$ hit the fragment is injected into the slot and ONLY the suffix tokens
 are prefilled — the paper's compute skip, for real.
 
+A finished prefill stores its slot as a fragment without waiting for it:
+one jitted program (``slot_fragment``) slices the slot into buffers of
+its own, and the ``FragmentCopier`` of the engine's device starts their
+copy to the host and collects it in a worker thread, while the host
+steps this engine and the others.  The store keeps the fragment's
+``Future`` under its key at once; a lookup that picks it waits for it
+then.  The engines on one device share one copier, which holds at most
+one fragment's device buffers (a save first waits for the copy before):
+a whole slot is 170 MB for Qwen3-4B, on a chip that four engines'
+caches and a whole-prompt prefill's temporaries fill to ~97%.
+
 ``EngineCluster`` wires N engines to a ``core.Router`` under a
 virtual-time event loop whose step durations are the *measured* wall
 times of the JAX computations, giving honest relative TTFT/TPOT between
@@ -20,22 +31,29 @@ live there); engines on one device share one copy of the params.
 records the span ``engine.step`` (iid, kind) around ``engine.admit``
 (slot zeroing, store lookup, fragment injection), ``engine.prefill``
 (dispatch to ``block_until_ready``), ``engine.fragment_save`` (the slot
-copied to the host and stored; bytes) and ``engine.decode`` (dispatch to
-the tokens on the host; bs), and the counters
-``engine.fragment_d2h_bytes``/``engine.fragment_h2d_bytes`` (fragment
-leaves' bytes) and ``engine.predicted_hit_tokens``/
-``engine.reused_tokens`` (the router's hit for an admitted request and
-the tokens the engine took from its store).  An MoE model's steps also
-count, from the ``moe_counts`` its programs return in the cache (a
-decode step computes every slot, a free one's stale token too):
+sliced, its copy to the host started and the pending fragment stored;
+bytes) and ``engine.decode`` (dispatch to the tokens on the host; bs),
+and the counters ``engine.fragment_d2h_bytes``/
+``engine.fragment_h2d_bytes`` (fragment leaves' bytes, counted as a copy
+starts and as a fragment is injected) and
+``engine.predicted_hit_tokens``/``engine.reused_tokens`` (the router's
+hit for an admitted request and the tokens the engine took from its
+store).  Each time the engine blocks on a copy still in flight (a save
+behind the one before, or a lookup that picked a pending fragment) it
+records the span ``engine.fragment_wait`` (inside
+``engine.fragment_save`` or ``engine.admit``) and counts
+``engine.fragment_waits`` and ``engine.fragment_wait_ns``: how often the
+overlap fails, and what it costs.  An MoE model's steps also count, from
+the ``moe_counts`` its programs return in the cache (a decode step
+computes every slot, a free one's stale token too):
 ``engine.moe_routed_local`` (routing choices that landed on a held
 expert, prefill and decode), and over decode steps and MoE layers
 ``engine.moe_layer_steps``, ``engine.moe_experts_touched`` (held experts
 given at least one choice) and ``engine.moe_max_expert_tokens`` (the
 most choices one held expert took).  With ``obs=None`` none of it runs
-and nothing is copied to the host (Contract 5).  The ``moe_tap`` that an
-MoE model's programs also leave in the cache (``Model``) stays on the
-device: the engine reads nothing of it.
+and nothing is copied to the host for it (Contract 5).  The ``moe_tap``
+that an MoE model's programs also leave in the cache (``Model``) stays
+on the device: the engine reads nothing of it.
 
 Encoder-decoder archs (whisper) are not served by this engine (the
 cluster simulator covers their scheduling); everything decoder-only —
@@ -44,6 +62,7 @@ dense, MoE, SSM, hybrid — works.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import functools
 import heapq
@@ -104,12 +123,93 @@ def _nbytes(tree) -> int:
 
 
 _NULL_CTX = contextlib.nullcontext()
+#: the copier's clock for ``engine.fragment_wait_ns``, apart from the
+#: step clock (``time.perf_counter``) that sets the cluster's virtual time
+_clock_ns = time.perf_counter_ns
+
+
+@jax.jit
+def slot_fragment(cache, b):
+    """Slot ``b`` of ``cache``'s units and rest as a B=1 view, in buffers
+    of its own: the steps that later overwrite the slot leave it as it
+    was.  ``b`` is traced, so one compile serves every slot."""
+    return _slice_slot(cache, b)
+
+
+def _to_host(leaves: list, treedef):
+    """The host copy of ``leaves`` (copies already started), as a tree;
+    each device buffer is dropped as soon as its host copy has landed."""
+    host = []
+    while leaves:
+        host.append(np.asarray(leaves.pop(0)))
+    return jax.tree.unflatten(treedef, host)
+
+
+class FragmentCopier:
+    """Copies prefix fragments from one device to the host in the
+    background, for every engine on that device.
+
+    ``start`` slices a slot out of a cache, starts the copy of the slice
+    and returns at once with a ``Future`` of the host tree; a worker
+    thread collects it.  At most one fragment's device buffers are in
+    flight: ``start`` first waits for the one before.  That bounds the
+    extra device memory at one cache slot, on a chip that the engines'
+    caches and a whole-prompt prefill's temporaries nearly fill.
+    ``drain`` waits for the copy in flight and stops the thread."""
+
+    def __init__(self):
+        self._pool: Optional[concurrent.futures.ThreadPoolExecutor] = None
+        self._inflight: Optional[concurrent.futures.Future] = None
+
+    def start(self, cache, b: int, obs=None) -> concurrent.futures.Future:
+        """Start copying slot ``b`` of ``cache`` to the host."""
+        if self._inflight is not None:
+            self.result(self._inflight, obs)
+        leaves, treedef = jax.tree.flatten(slot_fragment(
+            {"units": cache["units"], "rest": cache["rest"]}, b))
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        if self._pool is None:
+            self._pool = concurrent.futures.ThreadPoolExecutor(
+                1, thread_name_prefix="fragment-copy")
+        self._inflight = self._pool.submit(_to_host, leaves, treedef)
+        return self._inflight
+
+    def result(self, fut: concurrent.futures.Future, obs=None):
+        """The host tree of ``fut``; a wait on a copy still in flight is
+        spanned (``engine.fragment_wait``) and counted
+        (``engine.fragment_waits``, ``engine.fragment_wait_ns``)."""
+        if obs is None or fut.done():
+            return fut.result()
+        tr, reg = obs.tracer, obs.registry
+        t0 = _clock_ns()
+        with (tr.span("engine.fragment_wait") if tr is not None
+              else _NULL_CTX):
+            host = fut.result()
+        if reg is not None:
+            reg.inc("engine.fragment_waits")
+            reg.inc("engine.fragment_wait_ns", _clock_ns() - t0)
+        return host
+
+    def drain(self):
+        try:
+            if self._inflight is not None:
+                self._inflight.result()       # a failed copy raises here
+        finally:
+            if self._pool is not None:
+                self._pool.shutdown(wait=True)
+                self._pool = None
+            self._inflight = None
 
 
 class PrefixStore:
     """Host-side LRU store of per-slot cache fragments keyed by block-id
     chains.  ``exact_only`` archs (recurrent) store state snapshots; the
     mechanism is identical — inject fragment, resume at its length.
+
+    A fragment may be stored before its value exists (the engine stores
+    the ``Future`` of its copy to the host): the store only keeps it,
+    and evicting it just drops it.
 
     ``partial``: a fragment also serves a prompt that shares only the
     first blocks of its chain.  That is exact for full-attention KV
@@ -203,10 +303,13 @@ class InstanceEngine:
     def __init__(self, model: Model, params, iid: int = 0,
                  max_batch: int = 8, max_len: int = 512,
                  chunk_tokens: int = 128, block_size: int = 16,
-                 prefix_capacity: int = 64, device=None, obs=None):
+                 prefix_capacity: int = 64, device=None, obs=None,
+                 copier: Optional[FragmentCopier] = None):
         """``device``: where this engine's params and KV cache live
         (``None``: JAX's default device, params left where they are);
-        ``obs``: an observability bundle, or ``None`` (off)."""
+        ``obs``: an observability bundle, or ``None`` (off);
+        ``copier``: the ``FragmentCopier`` of the engines on ``device``
+        (``None``: one of its own)."""
         assert not model.cfg.is_encdec, "enc-dec not served by this engine"
         self.model = model
         self.device = device
@@ -224,6 +327,7 @@ class InstanceEngine:
         self.slot_bytes = _nbytes((self.cache["units"],
                                    self.cache["rest"])) // max_batch
         self.obs = obs
+        self.copier = copier if copier is not None else FragmentCopier()
         self.prefix_store = PrefixStore(
             prefix_capacity,
             partial=all(k == "attn" for k in model.cfg.block_pattern))
@@ -259,12 +363,13 @@ class InstanceEngine:
             reg.inc("engine.predicted_hit_tokens", seq.req.hit_tokens)
         blocks = tuple(tokens_to_blocks(seq.tokens.tolist(),
                                         self.block_size))
-        key, frag, length = self.prefix_store.lookup(blocks)
-        if frag is not None:
+        key, pending, length = self.prefix_store.lookup(blocks)
+        if pending is not None:
             # always leave >=1 token to prefill (logits source); a full-
             # prompt hit re-processes just the final token
             usable = min(length, len(seq.tokens) - 1)
             if usable > 0:
+                frag = self.copier.result(pending, self.obs)
                 self.cache = _write_slot(self.cache, frag, slot)
                 seq.pos = seq.reused = usable
                 seq.req.hit_tokens = usable
@@ -339,12 +444,13 @@ class InstanceEngine:
                                   args={"rid": seq.req.rid,
                                         "bytes": self.slot_bytes})
                           if tr is not None else _NULL_CTX):
-                        frag = jax.tree.map(
-                            np.asarray, _slice_slot(self.cache, seq.slot))
+                        pending = self.copier.start(self.cache, seq.slot,
+                                                    obs)
                         self.prefix_store.insert(
-                            blocks, frag, len(blocks) * self.block_size)
+                            blocks, pending, len(blocks) * self.block_size)
                     if reg is not None:
-                        reg.inc("engine.fragment_d2h_bytes", _nbytes(frag))
+                        reg.inc("engine.fragment_d2h_bytes",
+                                self.slot_bytes)
                 if seq.generated >= seq.req.output_len:
                     self._finish(seq, events)
         elif self.running:
@@ -385,6 +491,8 @@ class InstanceEngine:
         t = jnp.zeros((self.max_batch, 1), jnp.int32)
         p = jnp.zeros((self.max_batch,), jnp.int32)
         decode_step(self.model, self.params, c, t, p)[0].block_until_ready()
+        jax.block_until_ready(slot_fragment(
+            {"units": self.cache["units"], "rest": self.cache["rest"]}, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -404,9 +512,10 @@ class EngineCluster:
                 for i in range(n_instances)]
         placed = {d: params if d is None else jax.device_put(params, d)
                   for d in set(devs)}
+        self.copiers = {d: FragmentCopier() for d in placed}
         self.engines = [InstanceEngine(model, placed[d], iid=i, device=d,
                                        block_size=block_size, obs=obs,
-                                       **engine_kw)
+                                       copier=self.copiers[d], **engine_kw)
                         for i, d in enumerate(devs)]
         exact_only = not model.cfg.has_kv_blocks
         self.router = Router(policy, n_instances,
@@ -425,7 +534,18 @@ class EngineCluster:
         time, it returns follow-up arrival tuples (same shape) that are
         pushed into the live event heap — the real-engine analogue of
         ``repro.cluster.closed_loop``.
+
+        However it ends, the copies of prefix fragments still in flight
+        are collected before it returns: no device buffer or copier
+        thread outlives the call.
         """
+        try:
+            return self._run(arrivals, verbose, feedback)
+        finally:
+            for c in self.copiers.values():
+                c.drain()
+
+    def _run(self, arrivals, verbose, feedback) -> List[Request]:
         for e in self.engines:
             e.warmup()
         finished: List[Request] = []

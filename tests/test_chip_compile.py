@@ -3,7 +3,8 @@
 The TPU compiler is installed beside JAX, and it compiles for a chip
 that is described rather than present.  These tests compile the served
 path's device programs at their real sizes: the router's wave loop, a
-full-width qwen3_4b engine's jitted ``decode`` and ``prefill_slot``, and
+full-width qwen3_4b engine's jitted ``decode``, ``prefill_slot`` and
+``slot_fragment``, and
 those of a Qwen3-30B-A3B engine holding one chip's share of the experts
 (16 of 128 per layer, 8 slots of 1,152 positions).
 A compile that passes says nothing about results or times; it says the
@@ -94,6 +95,22 @@ def test_engine_prefill_slot_compiles_for_v5e(one_chip, qwen3_4b):
     compiled = prefill_slot.lower(model, params, cache, i32(1, CHUNK),
                                   i32(1, CHUNK), i32(), i32()).compile()
     _assert_fits(compiled)
+
+
+def test_engine_slot_fragment_compiles_for_v5e(one_chip, qwen3_4b):
+    """The save path's slice program, at the served cell's cache of 7
+    slots of 1,152 positions, holds one slot of the cache and no more."""
+    from repro.serving.engine import slot_fragment
+    model = qwen3_4b[0]
+    cache = _on(one_chip, jax.eval_shape(lambda: model.init_cache(7, 1152)))
+    compiled = slot_fragment.lower(
+        {"units": cache["units"], "rest": cache["rest"]},
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    m = compiled.memory_analysis()
+    slot = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache)) // 7
+    # the slot, and the output tuple's table of buffers
+    assert slot <= m.output_size_in_bytes <= slot + 4096
+    assert m.temp_size_in_bytes == 0
 
 
 A3B_BATCH, A3B_LEN, A3B_PROMPT = 8, 1152, 1152
